@@ -102,6 +102,8 @@ def main() -> None:
                     help="write executed suites' result dicts to PATH")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (depruning, device_tail, fig1_skew, fig3_io,
                             fig45_locality, fig6_cache_org, fleet_ops,
                             integrity_tail, interop_warmup, kernels,

@@ -40,6 +40,7 @@ import numpy as np
 from repro.core import DEVICES, SDMConfig, SDMEmbeddingStore
 from repro.core.power import HW_L, HW_SS, Workload, run_scenario
 from repro.devices import DeviceTuning, UpdateSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import dlrm
 from repro.runtime.engine import DeviceServingEngine, EngineConfig
 from repro.runtime.serve_sched import ServeConfig, ServeScheduler
@@ -64,6 +65,7 @@ def main():
                     help="sampled mode: apply the §4.1 tuning knobs "
                          "(outstanding-IO throttle + read-priority)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # model (small, materialized) + SDM inventory (M1-statistics, virtual)
     arch = dlrm.DLRMArch(user_tables=(50_000,) * 6, item_tables=(50_000,) * 3,

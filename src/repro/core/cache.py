@@ -22,12 +22,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-EMPTY = jnp.int32(-1)
+EMPTY = -1
 # Reserved query key that can never match a tag line: tags hold either EMPTY
 # (-1) or real (table >= 0, row >= 0) ids, so probing (NULL, NULL) is a
 # guaranteed miss. The sharded engine remaps keys it does not own to this
 # before the probe, so foreign keys neither hit nor perturb the LRU stamps.
-NULL_KEY = jnp.int32(-2)
+NULL_KEY = -2
 
 MEM_OPT_ROW_LIMIT = 255  # bytes; paper: dim <= 255B -> memory-optimized cache
 MEM_OPT_METADATA_B = 8
@@ -106,10 +106,10 @@ class JaxRowCache:
                       ) -> Tuple[jax.Array, jax.Array, dict]:
         """Probe through the ``cache_probe`` Pallas kernel (§4.3 hot path).
 
-        The kernel performs the data movement — per query, one cache set's tag
-        lines and data block move through VMEM and the hit row is selected
-        with a one-hot matmul — while the LRU metadata update (stamps, clock,
-        hit counters) stays in plain XLA, matching :meth:`lookup` exactly.
+        The kernel performs the way match and the data movement — per query,
+        a compare over the set's tag line and one DMA of the hit row — while
+        the LRU metadata update (stamps, clock, hit counters) stays in plain
+        XLA, matching :meth:`lookup` exactly.
 
         ``valid`` (bool [N], optional) masks out padded / foreign keys: they
         are probed as :data:`NULL_KEY` (guaranteed miss, no tag aliasing with

@@ -60,7 +60,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                  kv_len: jax.Array, *, block_s: int = 512,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """q: [B, H, hd]; k/v: [B, S, K, hd]; kv_len: [B] int32 (valid prefix).
     Returns attention output [B, H, hd] (f32).
     """

@@ -11,28 +11,25 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across JAX versions: newer releases type mesh axes
-    explicitly (``axis_types=Auto``); older ones (<= 0.4.x) have no
-    ``axis_types`` parameter and treat every axis as auto already."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings are propagated
+    by the compiler, as the sharding rules in ``launch.sharding`` expect
+    (``jax.make_mesh`` defaults to ``Explicit`` axes)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Degenerate mesh over the locally-available devices (CPU smoke tests)."""
     n = len(jax.devices())
     data = n // model_axis
-    return make_mesh_compat((data, model_axis), ("data", "model"))
+    return make_auto_mesh((data, model_axis), ("data", "model"))
 
 
 def make_embed_mesh(num_shards: int = 0):

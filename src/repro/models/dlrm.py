@@ -124,9 +124,21 @@ def serve_query(params: dict, user_idx: jax.Array, item_idx: jax.Array,
     Returns CTR scores [Bi].
     """
     n_user = len(arch.user_tables)
-    user_emb = embed_bags(params["tables"][:n_user], user_idx[:, None, :])  # [1, Tu, E]
+    user_emb = embed_bags(params["tables"][:n_user], user_idx[:, None, :])
+    return score_items(params, user_emb[0], item_idx, dense, arch)
+
+
+def score_items(params: dict, user_emb: jax.Array, item_idx: jax.Array,
+                dense: jax.Array, arch: DLRMArch) -> jax.Array:
+    """Rank one query's item batch (Eq. 2) from its pooled user bags.
+
+    user_emb: [Tu, E], from ``embed_bags`` or a serving engine's pooled
+    output; item_idx: [Ti, Bi, P]; dense: [Bi, num_dense].
+    Returns CTR scores [Bi].
+    """
+    n_user = len(arch.user_tables)
     Bi = dense.shape[0]
-    user_emb = jnp.broadcast_to(user_emb, (Bi,) + user_emb.shape[1:])
+    user_emb = jnp.broadcast_to(user_emb[None], (Bi,) + user_emb.shape)
     item_emb = embed_bags(params["tables"][n_user:], item_idx)              # [Bi, Ti, E]
     emb = jnp.concatenate([user_emb, item_emb], axis=1)
     z0 = _mlp(params["bottom"], dense, final_act=True)

@@ -5,9 +5,9 @@ live quantized in a (simulated) SM tier, hot dequantized rows live in an HBM
 row cache (``JaxRowCache``), and one jitted step serves a whole
 ``[batch, tables, pooling]`` index block:
 
-    probe   — ``cache_probe`` Pallas kernel: per query key, the cache set's
-              tag lines + data block move through VMEM, hit rows selected
-              with a one-hot matmul (§4.3).
+    probe   — ``cache_probe`` Pallas kernel: per query key, a scalar way
+              match over the set's tag line, and one DMA of the hit row
+              from the HBM cache (§4.3).
     gather  — misses are routed to the ``gather_pool`` Pallas kernel, which
               fuses gather + rowwise dequant + pooling over the quantized
               backing store (§4.4); hit positions point at a zero sentinel
@@ -19,8 +19,8 @@ The pooled output is the hit-side pool (from cache data) plus the miss-side
 pool (from the backing store). IO accounting happens host-side through the
 same analytic ``IOEngine`` the host store uses: the whole ``[batch, tables]``
 miss-count block goes through one coalesced ``submit_batch_multi`` call,
-giving per-query latencies under Eq. 3 overlap. On CPU the kernels run in
-interpret mode; on TPU they compile.
+giving per-query latencies under Eq. 3 overlap. On a TPU the kernels
+compile; on the CPU (the tests) they run in interpret mode.
 
 Miss accounting mirrors the host plane's unique-miss coalescing
 (``BatchedRowCache.access_batch``): repeated missed ``(table, row)`` keys in
@@ -44,6 +44,21 @@ from repro.core.io_sim import DeviceModel, IOEngine, IOQueueConfig
 from repro.core.quant import quantize_rows, row_bytes
 from repro.core.sdm import QueryStats
 from repro.kernels import ops
+
+
+@jax.jit
+def _quantize_all(tables):
+    return [{k: q[k] for k in ("payload", "scale", "bias")}
+            for q in map(quantize_rows, tables)]
+
+
+def quantize_tables(tables: Sequence) -> Tuple[List[np.ndarray], ...]:
+    """Row-quantize every table to 8 bits in one compiled program (one
+    compile, not one per op and table shape). Returns host copies
+    ``(payloads, scales, biases)``, one entry per table."""
+    qts = _quantize_all([jnp.asarray(t) for t in tables])
+    return tuple([np.asarray(q[k]) for q in qts]
+                 for k in ("payload", "scale", "bias"))
 
 
 def dense_from_chunk(chunk: ColumnarChunk, table_slot: Dict[int, int],
@@ -113,16 +128,22 @@ class DeviceServingEngine:
         self.rows_per_table = np.array([tables[t].shape[0]
                                         for t in self.table_ids], np.int64)
 
-        # quantize and stack into one backing store + zero sentinel row
-        qts = [quantize_rows(jnp.asarray(tables[t])) for t in self.table_ids]
-        payload = np.concatenate([np.asarray(q["payload"]) for q in qts])
-        scale = np.concatenate([np.asarray(q["scale"]) for q in qts])
-        bias = np.concatenate([np.asarray(q["bias"]) for q in qts])
-        self.payload = jnp.asarray(np.concatenate(
-            [payload, np.zeros((1, self.dim), payload.dtype)]))
-        self.scale = jnp.asarray(np.r_[scale, np.float32(0)])
-        self.bias = jnp.asarray(np.r_[bias, np.float32(0)])
-        self.sentinel = jnp.int32(payload.shape[0])          # the zero row
+        # quantize and stack into one backing store; zero rows after the
+        # tables (the first is the sentinel) pad it to whole kernel row
+        # groups, so the gather kernel needs no alignment copy
+        pls, scs, bss = quantize_tables([tables[t] for t in self.table_ids])
+        R = int(self.rows_per_table.sum())
+        n_store = ops.aligned_rows(R + 1)
+        payload = np.zeros((n_store, self.dim), pls[0].dtype)
+        payload[:R] = np.concatenate(pls)
+        scale = np.zeros(n_store, np.float32)
+        scale[:R] = np.concatenate(scs)
+        bias = np.zeros(n_store, np.float32)
+        bias[:R] = np.concatenate(bss)
+        self.payload = jnp.asarray(payload)
+        self.scale = jnp.asarray(scale)
+        self.bias = jnp.asarray(bias)
+        self.sentinel = R                                    # the zero row
         self.offsets = jnp.asarray(
             np.r_[0, np.cumsum(self.rows_per_table)[:-1]].astype(np.int32))
 
@@ -143,7 +164,7 @@ class DeviceServingEngine:
     def _make_step(self):
         cache, cfg = self.cache, self.cfg
 
-        def step(state, idx, valid):                         # idx [B, T, P]
+        def step(state, payload, scale, bias, idx, valid):  # idx [B, T, P]
             B, T, P = idx.shape
             tids = jnp.broadcast_to(
                 jnp.arange(T, dtype=jnp.int32)[None, :, None], idx.shape)
@@ -160,7 +181,7 @@ class DeviceServingEngine:
             gidx = jnp.where(hit | ~vq, self.sentinel, grow)
             gidx = gidx.reshape(B * T, P).astype(jnp.int32)
             pooled_miss = ops.embedding_gather_pool(
-                self.payload, self.scale, self.bias, gidx,
+                payload, scale, bias, gidx,
                 use_kernel=cfg.use_kernels).reshape(B, T, -1)
             # unique-miss coalescing (host parity): a repeated missed key is
             # one SM IO and one fill, charged to its first occurrence in
@@ -178,13 +199,24 @@ class DeviceServingEngine:
             io_mask = miss & first
             # fill: dequantize the fetched rows and insert (LRU eviction),
             # duplicates masked out so one scatter can't double-fill a set
-            deq = (self.payload[grow].astype(jnp.float32)
-                   * self.scale[grow][:, None] + self.bias[grow][:, None])
+            deq = (payload[grow].astype(jnp.float32)
+                   * scale[grow][:, None] + bias[grow][:, None])
             state = cache.insert(state, tq, rq, deq, mask=io_mask)
             miss_counts = jnp.sum(io_mask.reshape(B, T, P), axis=2)
             return state, pooled_hit + pooled_miss, miss_counts
 
         return step
+
+    def _step_args(self, idx, valid):
+        # the store goes in as arguments: arrays a jitted function closes
+        # over are embedded in the program as constants
+        return (self.state, self.payload, self.scale, self.bias,
+                jnp.asarray(idx), jnp.asarray(valid))
+
+    def lower_step(self, idx: np.ndarray, valid: np.ndarray):
+        """The jitted device step lowered for this ``[B, T, P]`` block, for
+        inspecting what it compiles to (kernels, memory)."""
+        return self._step.lower(*self._step_args(idx, valid))
 
     # -- serving --------------------------------------------------------------
 
@@ -209,8 +241,7 @@ class DeviceServingEngine:
             raise ValueError("row index out of range")
         if idx.shape[0] == 0:            # degenerate empty batch: no device
             return (np.zeros((0, idx.shape[1], self.dim), np.float32), [])
-        state, pooled, miss = self._step(self.state, jnp.asarray(idx),
-                                         jnp.asarray(valid))
+        state, pooled, miss = self._step(*self._step_args(idx, valid))
         self.state = state
         return np.asarray(pooled), self._account(np.asarray(miss), bg_iops)
 

@@ -43,19 +43,28 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.cache import JaxRowCache, dual_cache_geometry
 from repro.core.columnar import ColumnarChunk
 from repro.core.io_sim import DeviceModel, IOEngine
-from repro.core.quant import quantize_rows, row_bytes
+from repro.core.quant import row_bytes
 from repro.core.sdm import QueryStats
 from repro.kernels import ops
 from repro.launch.mesh import make_embed_mesh
 from repro.launch.sharding import (EMBED_LAYOUTS, embed_batch_specs,
                                    embed_cache_specs, embed_store_specs)
-from repro.runtime.engine import EngineConfig, dense_from_chunk
+from repro.runtime.engine import (EngineConfig, dense_from_chunk,
+                                  quantize_tables)
+
+
+def _shard_store(n: int, rows: int, dim: int, dtype):
+    """Zeroed per-shard store of ``rows`` table rows plus zero rows (the
+    first is the sentinel at index ``rows``) up to whole kernel row groups.
+    Returns (payload [n, L, dim], scale [n, L], bias [n, L])."""
+    L = ops.aligned_rows(rows + 1)
+    return (np.zeros((n, L, dim), dtype), np.zeros((n, L), np.float32),
+            np.zeros((n, L), np.float32))
 
 
 class ShardedServingEngine:
@@ -89,10 +98,7 @@ class ShardedServingEngine:
 
         # quantize whole tables first (bit-identical to the single-device
         # store), then slice rows into shards
-        qts = [quantize_rows(jnp.asarray(tables[t])) for t in self.table_ids]
-        pls = [np.asarray(q["payload"]) for q in qts]
-        scs = [np.asarray(q["scale"]) for q in qts]
-        bss = [np.asarray(q["bias"]) for q in qts]
+        pls, scs, bss = quantize_tables([tables[t] for t in self.table_ids])
         # global row ids (offsets into the unsharded concatenation) key the
         # cross-shard miss dedupe; they never index device memory here
         self.g_offsets = np.r_[0, np.cumsum(self.rows_per_table)[:-1]].astype(
@@ -105,9 +111,8 @@ class ShardedServingEngine:
                 np.int64)
             loff = np.r_[0, np.cumsum(self.slice_rows)[:-1]]
             L = int(self.slice_rows.sum())
-            payload = np.zeros((self.n, L + 1, self.dim), pls[0].dtype)
-            scale = np.zeros((self.n, L + 1), np.float32)
-            bias = np.zeros((self.n, L + 1), np.float32)
+            payload, scale, bias = _shard_store(self.n, L, self.dim,
+                                                pls[0].dtype)
             for ti in range(T):
                 s = int(self.slice_rows[ti])
                 for k in range(self.n):
@@ -133,9 +138,8 @@ class ShardedServingEngine:
                 loff[ti] = shard_rows[k]
                 shard_rows[k] += int(self.rows_per_table[ti])
             L = int(shard_rows.max())
-            payload = np.zeros((self.n, L + 1, self.dim), pls[0].dtype)
-            scale = np.zeros((self.n, L + 1), np.float32)
-            bias = np.zeros((self.n, L + 1), np.float32)
+            payload, scale, bias = _shard_store(self.n, L, self.dim,
+                                                pls[0].dtype)
             for ti in range(T):
                 k = int(self.owner_of_table[ti])
                 dst = int(loff[ti])
@@ -160,10 +164,12 @@ class ShardedServingEngine:
         self.cache = JaxRowCache(geo)
         cache_sh = {k: jax.sharding.NamedSharding(self.mesh, s)
                     for k, s in embed_cache_specs().items()}
+        # replicate on the host: a device-side broadcast would build all n
+        # copies on one device before slicing them out
         one = self.cache.init()
         self.state = {k: jax.device_put(
-            jnp.broadcast_to(v[None], (self.n,) + v.shape), cache_sh[k])
-            for k, v in one.items()}
+            np.broadcast_to(np.asarray(v)[None], (self.n,) + v.shape),
+            cache_sh[k]) for k, v in one.items()}
         self.io = IOEngine(device, cfg.num_devices, cfg.io_queue)
         self.stats = QueryStats()
         self.telemetry = None          # obs handle; None = bit-invisible
@@ -235,17 +241,23 @@ class ShardedServingEngine:
                     miss_counts[None])
 
         state_specs = embed_cache_specs()
-        sm = shard_map(
+        return jax.shard_map(
             shard_step, mesh=self.mesh,
             in_specs=(state_specs, P("shard", None, None), P("shard", None),
                       P("shard", None), b_specs["idx"], b_specs["valid"]),
             out_specs=(state_specs, b_specs["pooled"], b_specs["miss"]),
-            check_rep=False)
+            check_vma=False)
 
-        def step(state, idx, valid):
-            return sm(state, self.payload, self.scale, self.bias, idx, valid)
+    def _step_args(self, idx, valid):
+        # the store goes in as arguments: arrays a jitted function closes
+        # over are embedded in the program as constants
+        return (self.state, self.payload, self.scale, self.bias,
+                jnp.asarray(idx), jnp.asarray(valid))
 
-        return step
+    def lower_step(self, idx: np.ndarray, valid: np.ndarray):
+        """The jitted device step lowered for this ``[B, T, P]`` block, for
+        inspecting what it compiles to (kernels, memory)."""
+        return self._step.lower(*self._step_args(idx, valid))
 
     # -- serving --------------------------------------------------------------
 
@@ -268,8 +280,7 @@ class ShardedServingEngine:
             raise ValueError("row index out of range")
         if idx.shape[0] == 0:
             return (np.zeros((0, idx.shape[1], self.dim), np.float32), [])
-        state, pooled, miss = self._step(self.state, jnp.asarray(idx),
-                                         jnp.asarray(valid))
+        state, pooled, miss = self._step(*self._step_args(idx, valid))
         self.state = state
         return np.asarray(pooled), self._account(np.asarray(miss), bg_iops)
 

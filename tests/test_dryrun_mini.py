@@ -19,10 +19,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import SHAPES, get_config
 from repro.launch import sharding as sh
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_auto_mesh
 from repro.models.layers import set_logical_rules
 
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_auto_mesh((4, 2), ("data", "model"))
 cfg = get_config("smollm-135m").reduced()
 import dataclasses
 shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64, global_batch=8)

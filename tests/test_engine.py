@@ -211,3 +211,26 @@ def test_coalesced_io_matches_per_table_submit():
     np.testing.assert_array_equal(sm_multi, sm_ref)
     assert (io_a.total_ios, io_a.total_bus_bytes, io_a.total_wanted_bytes) \
         == (io_b.total_ios, io_b.total_bus_bytes, io_b.total_wanted_bytes)
+
+
+@pytest.mark.parametrize("layout", [None, "row", "table"])
+def test_step_takes_the_store_as_arguments(layout):
+    # arrays a jitted function closes over are embedded in the program as
+    # constants: a 1 GiB store would become a 1 GiB literal in the step
+    from repro.launch.mesh import make_embed_mesh
+    from repro.runtime.sharded_engine import ShardedServingEngine
+    rng = np.random.default_rng(3)
+    tables = {i: rng.standard_normal((100, 8)).astype(np.float32)
+              for i in range(2)}
+    cfg = EngineConfig(hbm_cache_bytes=1 << 16)
+    eng = (DeviceServingEngine(tables, DEVICES["nand_flash"], cfg)
+           if layout is None else
+           ShardedServingEngine(tables, DEVICES["nand_flash"], cfg,
+                                mesh=make_embed_mesh(1), layout=layout))
+    idx = rng.integers(0, 100, (3, 2, 4)).astype(np.int32)
+    text = eng.lower_step(idx, np.ones(idx.shape, bool)).as_text()
+    store = "x".join(map(str, eng.payload.shape)) + "xui8"
+    main = next(line for line in text.splitlines() if "@main(" in line)
+    assert store in main
+    assert "constant dense" not in "\n".join(
+        line for line in text.splitlines() if store in line)

@@ -158,3 +158,25 @@ def test_decode_attention_matches_model_attention():
                             block_s=128, interpret=True)
     np.testing.assert_allclose(np.asarray(model_out[:, 0]), np.asarray(kern_out),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# backend guard
+# ---------------------------------------------------------------------------
+
+
+def test_ops_refuse_backends_other_than_cpu_and_tpu(monkeypatch):
+    # interpret mode is for the CPU only: on any other backend the wrappers
+    # raise instead of quietly interpreting the kernels
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    payload = jnp.zeros((8, 128), jnp.uint8)
+    ones = jnp.ones(8, jnp.float32)
+    idx = jnp.zeros((1, 2), jnp.int32)
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.embedding_gather_pool(payload, ones, ones, idx)
+    tags = jnp.zeros((2, 2), jnp.int32)
+    q = jnp.zeros((1,), jnp.int32)
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.row_cache_probe(tags, tags, jnp.zeros((2, 2, 128)), q, q, q)
+    assert ops.embedding_gather_pool(payload, ones, ones, idx,
+                                     use_kernel=False).shape == (1, 128)

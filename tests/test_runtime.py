@@ -68,8 +68,8 @@ def test_restore_with_shardings(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     state = {"w": jnp.arange(8.0)}
     mgr.save(state, 5)
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    from repro.launch.mesh import make_auto_mesh
+    mesh = make_auto_mesh((1,), ("data",))
     sh = {"w": jax.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))}
     restored, _ = mgr.restore(state, shardings=sh)
     assert restored["w"].sharding == sh["w"]
