@@ -22,6 +22,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import tracing as names
+
 EMPTY = -1
 # Reserved query key that can never match a tag line: tags hold either EMPTY
 # (-1) or real (table >= 0, row >= 0) ids, so probing (NULL, NULL) is a
@@ -127,18 +129,22 @@ class JaxRowCache:
             state["tag_table"], state["tag_row"], state["data"],
             tables, rows, sets, use_kernel=use_kernel)
         hit = hit_i.astype(bool)
-        match = ((state["tag_table"][sets] == tables[:, None]) &
-                 (state["tag_row"][sets] == rows[:, None]))
-        way = jnp.argmax(match, axis=1)
-        clock = state["clock"] + 1
-        stamp = state["stamp"].at[
-            jnp.where(hit, sets, jnp.int32(g.num_sets)), way].set(
-            clock, mode="drop")
-        counted_hit = hit if valid is None else (hit & valid)
-        counted_miss = (~hit) if valid is None else ((~hit) & valid)
-        new_state = dict(state, stamp=stamp, clock=clock,
-                         hits=state["hits"] + jnp.sum(counted_hit, dtype=jnp.int32),
-                         misses=state["misses"] + jnp.sum(counted_miss, dtype=jnp.int32))
+        with jax.named_scope(names.SCOPE_REMATCH):
+            match = ((state["tag_table"][sets] == tables[:, None]) &
+                     (state["tag_row"][sets] == rows[:, None]))
+            way = jnp.argmax(match, axis=1)
+        with jax.named_scope(names.SCOPE_STAMP):
+            clock = state["clock"] + 1
+            stamp = state["stamp"].at[
+                jnp.where(hit, sets, jnp.int32(g.num_sets)), way].set(
+                clock, mode="drop")
+            counted_hit = hit if valid is None else (hit & valid)
+            counted_miss = (~hit) if valid is None else ((~hit) & valid)
+            new_state = dict(
+                state, stamp=stamp, clock=clock,
+                hits=state["hits"] + jnp.sum(counted_hit, dtype=jnp.int32),
+                misses=state["misses"] + jnp.sum(counted_miss,
+                                                 dtype=jnp.int32))
         return values.astype(self.dtype), hit, new_state
 
     def insert(self, state: dict, tables: jax.Array, rows: jax.Array,
@@ -159,40 +165,45 @@ class JaxRowCache:
         if mask is None:
             mask = jnp.ones(tables.shape, bool)
         sets = set_index(tables, rows, g.num_sets)
-        match = ((state["tag_table"][sets] == tables[:, None]) &
-                 (state["tag_row"][sets] == rows[:, None]))
-        already = jnp.any(match, axis=1)
-        # Rank each new masked key within its set (stable order of appearance):
-        # sort keys by set id, number the positions inside each run.
-        n = tables.shape[0]
-        is_new = mask & ~already
-        rank_key = jnp.where(is_new, sets, jnp.int32(g.num_sets))  # park others
-        order = jnp.argsort(rank_key, stable=True)
-        sorted_sets = rank_key[order]
-        pos = jnp.arange(n, dtype=jnp.int32)
-        run_start = jnp.concatenate(
-            [jnp.ones((1,), bool), sorted_sets[1:] != sorted_sets[:-1]])
-        start_pos = jax.lax.cummax(jnp.where(run_start, pos, 0))
-        rank = jnp.zeros((n,), jnp.int32).at[order].set(pos - start_pos)
-        # way for a new key = its rank-th entry of the set's LRU order (oldest
-        # stamp first); ranks past the associativity wrap — the sequential
-        # equivalent, since rank W would evict rank 0's freshly-filled way.
-        lru_order = jnp.argsort(state["stamp"][sets], axis=1)      # [N, W]
-        way_new = jnp.take_along_axis(
-            lru_order, (rank % g.ways)[:, None], axis=1)[:, 0]
-        way = jnp.where(already, jnp.argmax(match, axis=1), way_new)
-        # Masked-out entries scatter out of bounds and are dropped. (The
-        # previous scheme — redirect them to (0, 0) and write the old value
-        # back — raced real inserts targeting slot (0, 0) in the same
-        # scatter: a later masked element re-wrote the stale EMPTY tag.)
-        sets_w = jnp.where(mask, sets, jnp.int32(g.num_sets))
-        clock = state["clock"] + 1
-
-        tt = state["tag_table"].at[sets_w, way].set(tables, mode="drop")
-        tr = state["tag_row"].at[sets_w, way].set(rows, mode="drop")
-        data = state["data"].at[sets_w, way].set(
-            values.astype(self.dtype), mode="drop")
-        stamp = state["stamp"].at[sets_w, way].set(clock, mode="drop")
+        with jax.named_scope(names.SCOPE_RANK):
+            match = ((state["tag_table"][sets] == tables[:, None]) &
+                     (state["tag_row"][sets] == rows[:, None]))
+            already = jnp.any(match, axis=1)
+            # Rank each new masked key within its set (stable order of
+            # appearance): sort keys by set id, number the positions inside
+            # each run.
+            n = tables.shape[0]
+            is_new = mask & ~already
+            rank_key = jnp.where(is_new, sets, jnp.int32(g.num_sets))  # park
+            order = jnp.argsort(rank_key, stable=True)
+            sorted_sets = rank_key[order]
+            pos = jnp.arange(n, dtype=jnp.int32)
+            run_start = jnp.concatenate(
+                [jnp.ones((1,), bool), sorted_sets[1:] != sorted_sets[:-1]])
+            start_pos = jax.lax.cummax(jnp.where(run_start, pos, 0))
+            rank = jnp.zeros((n,), jnp.int32).at[order].set(pos - start_pos)
+        with jax.named_scope(names.SCOPE_LRU):
+            # way for a new key = its rank-th entry of the set's LRU order
+            # (oldest stamp first); ranks past the associativity wrap — the
+            # sequential equivalent, since rank W would evict rank 0's
+            # freshly-filled way.
+            lru_order = jnp.argsort(state["stamp"][sets], axis=1)  # [N, W]
+            way_new = jnp.take_along_axis(
+                lru_order, (rank % g.ways)[:, None], axis=1)[:, 0]
+            way = jnp.where(already, jnp.argmax(match, axis=1), way_new)
+        with jax.named_scope(names.SCOPE_SCATTER):
+            # Masked-out entries scatter out of bounds and are dropped. (The
+            # previous scheme — redirect them to (0, 0) and write the old
+            # value back — raced real inserts targeting slot (0, 0) in the
+            # same scatter: a later masked element re-wrote the stale EMPTY
+            # tag.)
+            sets_w = jnp.where(mask, sets, jnp.int32(g.num_sets))
+            clock = state["clock"] + 1
+            tt = state["tag_table"].at[sets_w, way].set(tables, mode="drop")
+            tr = state["tag_row"].at[sets_w, way].set(rows, mode="drop")
+            data = state["data"].at[sets_w, way].set(
+                values.astype(self.dtype), mode="drop")
+            stamp = state["stamp"].at[sets_w, way].set(clock, mode="drop")
         return dict(state, tag_table=tt, tag_row=tr, data=data,
                     stamp=stamp, clock=clock)
 

@@ -1,4 +1,5 @@
-"""Sampling span recorder on the simulated clock.
+"""Sampling span recorder on the simulated clock, and the names of the
+device engine's wall-clock spans and device scopes.
 
 Spans are stamped with **simulated microseconds** (`at_us` from the serve
 scheduler / device clock), never wallclock — the recorder consumes no RNG
@@ -19,6 +20,37 @@ metadata events.
 from __future__ import annotations
 
 from typing import Dict, List, Optional
+
+# -- device engine: names on the profiler's clock ----------------------------
+# ``runtime/engine.py`` marks its host path with ``jax.profiler
+# .TraceAnnotation`` spans and the regions of its jitted step with
+# ``jax.named_scope``; ``core/cache.py`` scopes the cache's own parts inside
+# them. Both land in the JAX profiler's trace, on the one clock it shares
+# with the device's ops, and record only while a profiler session is
+# active. They are not SpanRecorder events. Code that reads such a trace
+# takes the names from here.
+SPAN_SERVE = "engine.serve"          # all of serve_columnar; parent of the rest
+SPAN_PACK = "engine.pack"            # dense_from_chunk: CSR -> [B, T, P] block
+SPAN_VALIDATE = "engine.validate"    # shape and row-range checks of the block
+SPAN_DISPATCH = "engine.dispatch"    # host-to-device copies + the async step call
+SPAN_FETCH = "engine.fetch"          # wait for the step, copy results back
+SPAN_ACCOUNT = "engine.account"      # IO accounting, per-query result arrays
+ENGINE_SPANS = (SPAN_SERVE, SPAN_PACK, SPAN_VALIDATE, SPAN_DISPATCH,
+                SPAN_FETCH, SPAN_ACCOUNT)
+
+SCOPE_PROBE = "engine.probe"         # set index, probe kernel, stamp update
+SCOPE_GATHER = "engine.gather"       # hit-side pool, gather-pool of misses
+SCOPE_DEDUPE = "engine.dedupe"       # stable sort of missed rows, group heads
+SCOPE_FILL = "engine.fill"           # dequantize fetched rows, cache insert
+ENGINE_SCOPES = (SCOPE_PROBE, SCOPE_GATHER, SCOPE_DEDUPE, SCOPE_FILL)
+
+SCOPE_REMATCH = "cache.rematch"      # XLA tag-line gather + compare after the kernel
+SCOPE_STAMP = "cache.stamp"          # LRU stamp scatter, hit/miss counts
+SCOPE_RANK = "cache.rank"            # insert: tag compare, rank within set
+SCOPE_LRU = "cache.lru"              # insert: LRU order of each set's ways
+SCOPE_SCATTER = "cache.scatter"      # insert: tag, data and stamp scatters
+CACHE_SCOPES = (SCOPE_REMATCH, SCOPE_STAMP, SCOPE_RANK, SCOPE_LRU,
+                SCOPE_SCATTER)
 
 # Event tuples: (ts_us, dur_us, ph, name, cat, pid_label, args)
 _PH_SPAN = "X"

@@ -28,6 +28,12 @@ one batch cost one SM IO — charged to the first occurrence in query order,
 exactly where a sequential run would take the miss before the fill makes
 every later occurrence a hit — and fill the cache once (duplicates are
 masked out of ``cache.insert`` so one scatter can't double-fill an LRU set).
+
+Instrumentation, on the JAX profiler's clock: the host path's steps are
+``jax.profiler.TraceAnnotation`` spans and the step's regions are
+``jax.named_scope``s, both named in ``repro.obs.tracing``; with a telemetry
+handle attached, each step adds its block's positions and valid positions
+to ``engine.positions`` and ``engine.valid_positions``.
 """
 from __future__ import annotations
 
@@ -44,6 +50,9 @@ from repro.core.io_sim import DeviceModel, IOEngine, IOQueueConfig
 from repro.core.quant import quantize_rows, row_bytes
 from repro.core.sdm import QueryStats
 from repro.kernels import ops
+from repro.obs import tracing as names
+
+TraceAnnotation = jax.profiler.TraceAnnotation
 
 
 @jax.jit
@@ -164,6 +173,8 @@ class DeviceServingEngine:
     def _make_step(self):
         cache, cfg = self.cache, self.cfg
 
+        # each region is a named scope, so the profiler's device ops carry
+        # it in their op_name metadata (``repro.obs.tracing``)
         def step(state, payload, scale, bias, idx, valid):  # idx [B, T, P]
             B, T, P = idx.shape
             tids = jnp.broadcast_to(
@@ -171,38 +182,43 @@ class DeviceServingEngine:
             tq = tids.reshape(-1)
             rq = idx.reshape(-1)
             vq = valid.reshape(-1)
-            vals, hit, state = cache.lookup_device(
-                state, tq, rq, use_kernel=cfg.use_kernels, valid=vq)
-            # hit-side pool straight from HBM cache data
-            pooled_hit = (vals * hit[:, None]).reshape(B, T, P, -1).sum(axis=2)
-            # miss-side pool fused over the quantized backing store; hits and
-            # padded positions are pointed at the zero sentinel row
-            grow = (self.offsets[tids] + idx).reshape(-1)
-            gidx = jnp.where(hit | ~vq, self.sentinel, grow)
-            gidx = gidx.reshape(B * T, P).astype(jnp.int32)
-            pooled_miss = ops.embedding_gather_pool(
-                payload, scale, bias, gidx,
-                use_kernel=cfg.use_kernels).reshape(B, T, -1)
-            # unique-miss coalescing (host parity): a repeated missed key is
-            # one SM IO and one fill, charged to its first occurrence in
-            # flattened (query, table, position) order — the element a
-            # sequential run would miss on before its fill turns the rest
-            # into hits. Group equal global rows with a stable sort; the
-            # group head is the first occurrence.
-            miss = vq & ~hit
-            gkey = jnp.where(miss, grow, jnp.int32(-1))      # -1: one dead group
-            order = jnp.argsort(gkey, stable=True)
-            ks = gkey[order]
-            head = jnp.concatenate(
-                [jnp.ones((1,), bool), ks[1:] != ks[:-1]])
-            first = jnp.zeros(gkey.shape, bool).at[order].set(head)
-            io_mask = miss & first
-            # fill: dequantize the fetched rows and insert (LRU eviction),
-            # duplicates masked out so one scatter can't double-fill a set
-            deq = (payload[grow].astype(jnp.float32)
-                   * scale[grow][:, None] + bias[grow][:, None])
-            state = cache.insert(state, tq, rq, deq, mask=io_mask)
-            miss_counts = jnp.sum(io_mask.reshape(B, T, P), axis=2)
+            with jax.named_scope(names.SCOPE_PROBE):
+                vals, hit, state = cache.lookup_device(
+                    state, tq, rq, use_kernel=cfg.use_kernels, valid=vq)
+            with jax.named_scope(names.SCOPE_GATHER):
+                # hit-side pool straight from HBM cache data
+                pooled_hit = (vals * hit[:, None]).reshape(
+                    B, T, P, -1).sum(axis=2)
+                # miss-side pool fused over the quantized backing store; hits
+                # and padded positions are pointed at the zero sentinel row
+                grow = (self.offsets[tids] + idx).reshape(-1)
+                gidx = jnp.where(hit | ~vq, self.sentinel, grow)
+                gidx = gidx.reshape(B * T, P).astype(jnp.int32)
+                pooled_miss = ops.embedding_gather_pool(
+                    payload, scale, bias, gidx,
+                    use_kernel=cfg.use_kernels).reshape(B, T, -1)
+            with jax.named_scope(names.SCOPE_DEDUPE):
+                # unique-miss coalescing (host parity): a repeated missed key
+                # is one SM IO and one fill, charged to its first occurrence
+                # in flattened (query, table, position) order — the element a
+                # sequential run would miss on before its fill turns the rest
+                # into hits. Group equal global rows with a stable sort; the
+                # group head is the first occurrence.
+                miss = vq & ~hit
+                gkey = jnp.where(miss, grow, jnp.int32(-1))  # -1: one dead group
+                order = jnp.argsort(gkey, stable=True)
+                ks = gkey[order]
+                head = jnp.concatenate(
+                    [jnp.ones((1,), bool), ks[1:] != ks[:-1]])
+                first = jnp.zeros(gkey.shape, bool).at[order].set(head)
+                io_mask = miss & first
+                miss_counts = jnp.sum(io_mask.reshape(B, T, P), axis=2)
+            with jax.named_scope(names.SCOPE_FILL):
+                # dequantize the fetched rows and insert (LRU eviction),
+                # duplicates masked out so one scatter can't double-fill a set
+                deq = (payload[grow].astype(jnp.float32)
+                       * scale[grow][:, None] + bias[grow][:, None])
+                state = cache.insert(state, tq, rq, deq, mask=io_mask)
             return state, pooled_hit + pooled_miss, miss_counts
 
         return step
@@ -227,23 +243,47 @@ class DeviceServingEngine:
         ``table_ids``). Returns (pooled [B, T, dim] f32, per-query stats).
         ``valid`` (bool [B, T, P], optional) masks padded positions out of
         pooling, caching and IO accounting."""
-        idx = np.asarray(idx, np.int32)
-        if idx.ndim != 3:
-            raise ValueError(f"idx must be [B, T, P], got shape {idx.shape}")
-        if idx.shape[1] != len(self.table_ids):
-            raise ValueError(
-                f"idx has {idx.shape[1]} tables, engine has "
-                f"{len(self.table_ids)}")
-        if valid is None:
-            valid = np.ones(idx.shape, bool)
-        live = np.where(valid, idx, 0)
-        if (live < 0).any() or (live >= self.rows_per_table[None, :, None]).any():
-            raise ValueError("row index out of range")
+        idx, valid = self._checked(idx, valid)
         if idx.shape[0] == 0:            # degenerate empty batch: no device
             return (np.zeros((0, idx.shape[1], self.dim), np.float32), [])
-        state, pooled, miss = self._step(*self._step_args(idx, valid))
+        pooled, miss = self._run_step(idx, valid)
+        with TraceAnnotation(names.SPAN_ACCOUNT):
+            return pooled, self._account(miss, bg_iops)
+
+    def _checked(self, idx, valid) -> Tuple[np.ndarray, np.ndarray]:
+        """``(idx, valid)`` as int32 and bool ``[B, T, P]`` blocks; raises
+        on a wrong shape or a valid row index out of its table's range."""
+        with TraceAnnotation(names.SPAN_VALIDATE):
+            idx = np.asarray(idx, np.int32)
+            if idx.ndim != 3:
+                raise ValueError(
+                    f"idx must be [B, T, P], got shape {idx.shape}")
+            if idx.shape[1] != len(self.table_ids):
+                raise ValueError(
+                    f"idx has {idx.shape[1]} tables, engine has "
+                    f"{len(self.table_ids)}")
+            if valid is None:
+                valid = np.ones(idx.shape, bool)
+            live = np.where(valid, idx, 0)
+            if ((live < 0).any()
+                    or (live >= self.rows_per_table[None, :, None]).any()):
+                raise ValueError("row index out of range")
+        return idx, valid
+
+    def _run_step(self, idx: np.ndarray, valid: np.ndarray
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+        """Run the device step on a checked, non-empty block and wait for
+        it: ``(pooled [B, T, dim], deduped miss counts [B, T])`` on the
+        host."""
+        if self.telemetry is not None:
+            reg = self.telemetry.registry
+            reg.inc("engine.positions", valid.size)
+            reg.inc("engine.valid_positions", int(np.count_nonzero(valid)))
+        with TraceAnnotation(names.SPAN_DISPATCH):
+            state, pooled, miss = self._step(*self._step_args(idx, valid))
         self.state = state
-        return np.asarray(pooled), self._account(np.asarray(miss), bg_iops)
+        with TraceAnnotation(names.SPAN_FETCH):
+            return np.asarray(pooled), np.asarray(miss)
 
     def serve_columnar(self, chunk: ColumnarChunk, bg_iops: float = 0.0
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -253,14 +293,18 @@ class DeviceServingEngine:
         i64)`` with T in ``table_ids`` order (tables a query does not touch
         pool to zero)."""
         T = len(self.table_ids)
-        if chunk.n_queries == 0:
-            return (np.zeros((0, T, self.dim), np.float32),
-                    np.zeros(0, np.float64), np.zeros(0, np.int64))
-        idx, valid = dense_from_chunk(chunk, self.table_slot, T)
-        pooled, stats = self.serve_batch(idx, bg_iops, valid=valid)
-        return (pooled,
-                np.array([s.sm_time_us for s in stats], np.float64),
-                np.array([s.sm_ios for s in stats], np.int64))
+        with TraceAnnotation(names.SPAN_SERVE):
+            if chunk.n_queries == 0:
+                return (np.zeros((0, T, self.dim), np.float32),
+                        np.zeros(0, np.float64), np.zeros(0, np.int64))
+            with TraceAnnotation(names.SPAN_PACK):
+                idx, valid = dense_from_chunk(chunk, self.table_slot, T)
+            pooled, miss = self._run_step(*self._checked(idx, valid))
+            with TraceAnnotation(names.SPAN_ACCOUNT):
+                stats = self._account(miss, bg_iops)
+                return (pooled,
+                        np.array([s.sm_time_us for s in stats], np.float64),
+                        np.array([s.sm_ios for s in stats], np.int64))
 
     def _account(self, miss: np.ndarray, bg_iops: float) -> List[QueryStats]:
         """Per-query IO + Eq. 3 latency accounting for a ``[B, T]`` block of

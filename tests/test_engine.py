@@ -234,3 +234,52 @@ def test_step_takes_the_store_as_arguments(layout):
     assert store in main
     assert "constant dense" not in "\n".join(
         line for line in text.splitlines() if store in line)
+
+
+def test_step_regions_carry_their_scopes():
+    """The jitted step's regions are named scopes, so each device op's
+    op_name metadata says which region (and cache part) it belongs to."""
+    from repro.obs import tracing as names
+    rng = np.random.default_rng(6)
+    eng = DeviceServingEngine(
+        {i: rng.standard_normal((50, 8)).astype(np.float32) for i in range(2)},
+        DEVICES["nand_flash"], EngineConfig(hbm_cache_bytes=1 << 14))
+    idx = rng.integers(0, 50, (3, 2, 4)).astype(np.int32)
+    lowered = eng.lower_step(idx, np.ones(idx.shape, bool))
+    text = lowered.as_text(debug_info=True)
+    for scope in names.ENGINE_SCOPES:
+        assert f'"jit(step)/{scope}/' in text, scope
+    for scope in names.CACHE_SCOPES:
+        assert f"/{scope}/" in text, scope
+    assert lowered.compile().as_text().count("engine.") > 0
+    assert eng._step.__wrapped__.__name__ == "step"      # program jit_step
+
+
+def test_padding_counters_and_invisible_without_handle():
+    """With a telemetry handle the engine counts each block's positions
+    (B x T x P) and the valid ones (the chunk's lookups); without one it
+    records nothing and serves bit-identically."""
+    from repro.core.columnar import ColumnarQueries
+    from repro.obs import make_telemetry
+    rng = np.random.default_rng(10)
+    tables = {t: rng.standard_normal((60, 8)).astype(np.float32)
+              for t in (2, 4, 7)}
+    reqs = [{t: rng.integers(0, 60, rng.integers(1, 6)) for t in tables
+             if rng.random() < 0.8} for _ in range(5)]
+    chunk = ColumnarQueries.from_requests(reqs).whole()
+    lookups = sum(len(v) for r in reqs for v in r.values())
+    engines = [DeviceServingEngine(tables, DEVICES["nand_flash"],
+                                   EngineConfig(hbm_cache_bytes=1 << 13))
+               for _ in range(2)]
+    engines[1].telemetry = make_telemetry(True)
+    outs = [[e.serve_columnar(chunk) for _ in range(2)] for e in engines]
+    idx, valid = dense_from_chunk(chunk, engines[0].table_slot, 3)
+    counters = engines[1].telemetry.registry.counters
+    assert counters["engine.positions"] == 2 * idx.size
+    assert counters["engine.valid_positions"] == 2 * lookups == 2 * valid.sum()
+    assert engines[0].telemetry is None
+    for a, b in zip(*outs):
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(x, y)
+    for k in engines[0].state:
+        np.testing.assert_array_equal(engines[0].state[k], engines[1].state[k])
