@@ -19,6 +19,9 @@ what ``SDMEmbeddingStore.serve_columnar`` consumes — is then pure slicing:
 each table's share of a query range ``[qs, qe)`` is one contiguous span of
 the grouped arrays (found by ``searchsorted``), so per-chunk per-table
 grouping costs O(tables), not O(batch x tables) Python.
+:meth:`ColumnarChunk.segments` gives the same range query-major, with no
+grouping: one contiguous span of segments and of ``values``, which the
+device engine packs into its dense block in one pass.
 
 ``requests()`` materializes the dict-of-arrays view once (arrays are views
 into ``values``) — the compatibility adapter for the dict entry points and
@@ -55,6 +58,18 @@ class TableView:
     vals: np.ndarray                 # [nnz_t] concatenated indices
     keys: np.ndarray                 # [nnz_t] composite (table, row) keys
     hashes: Optional[np.ndarray]     # [Sl] uint64 order-invariant hashes
+
+
+@dataclasses.dataclass(frozen=True)
+class ChunkSegments:
+    """A chunk's segments in query-major order, straight from the parent's
+    CSR arrays (no table grouping): per segment the chunk-local query id,
+    the table id and the length, and the chunk's contiguous ``values``
+    slice they index in order."""
+    qid: np.ndarray                  # [S] local query id, ascending
+    tid: np.ndarray                  # [S] table id
+    lens: np.ndarray                 # [S] indices per segment
+    vals: np.ndarray                 # [nnz] concatenated indices
 
 
 class _Grouping:
@@ -323,6 +338,19 @@ class ColumnarChunk:
         """Most tables any query of the chunk touches (event-rank width)."""
         nseg = self._p.nseg[self._qs:self._qe]
         return int(nseg.max()) if len(nseg) else 0
+
+    def segments(self) -> ChunkSegments:
+        """The chunk's query-major CSR slice: queries ``[qs, qe)`` own one
+        contiguous span of segments and one of ``values``."""
+        p = self._p
+        qseg = p.query_seg[self._qs:self._qe + 1]
+        s0, s1 = int(qseg[0]), int(qseg[-1])
+        so = p.seg_offsets[s0:s1 + 1]
+        return ChunkSegments(
+            qid=np.repeat(np.arange(self.n_queries, dtype=np.int64),
+                          np.diff(qseg)),
+            tid=p.seg_table[s0:s1], lens=np.diff(so),
+            vals=p.values[int(so[0]):int(so[-1])])
 
     def table_views(self, with_hashes: bool = False) -> List[TableView]:
         g = self._p.group()

@@ -78,28 +78,31 @@ def dense_from_chunk(chunk: ColumnarChunk, table_slot: Dict[int, int],
     (bounding jit recompiles across chunks); absent/padded positions get
     index 0 with ``valid=False`` — the device step routes them to the zero
     sentinel row so they contribute nothing and cost no IO.
+
+    One vectorized pass over the chunk's query-major CSR slice: its cost
+    follows the lookups, not the table count. A table of the chunk that
+    ``table_slot`` lacks raises ``KeyError``.
     """
     B = chunk.n_queries
-    views = chunk.table_views()
-    P = 1
-    for v in views:
-        if len(v.lens):
-            P = max(P, int(v.lens.max()))
+    seg = chunk.segments()
+    P = max(int(seg.lens.max(initial=0)), 1)
     P = 1 << (P - 1).bit_length()
-    idx = np.zeros((B, num_tables, P), np.int32)
-    valid = np.zeros((B, num_tables, P), bool)
-    for v in views:
-        t = table_slot[v.tid]
-        nseg = len(v.qid)
-        if nseg == 0 or not len(v.vals):
-            continue
-        seg = np.repeat(np.arange(nseg, dtype=np.int64), v.lens)
-        pos = (np.arange(len(v.vals), dtype=np.int64)
-               - np.repeat(v.eoff[:-1], v.lens))
-        b = v.qid[seg]
-        idx[b, t, pos] = v.vals
-        valid[b, t, pos] = True
-    return idx, valid
+    idx = np.zeros(B * num_tables * P, np.int32)
+    valid = np.zeros(B * num_tables * P, bool)
+    if len(seg.tid):
+        # table id -> slot: one dict lookup per distinct table
+        tids, inv = np.unique(seg.tid, return_inverse=True)
+        slot = np.array([table_slot[t] for t in tids.tolist()],
+                        np.int64)[inv]
+        # flat offset of a segment's first element, less its CSR offset
+        start = (seg.qid * num_tables + slot) * P
+        eoff = np.cumsum(seg.lens) - seg.lens
+        flat = (np.repeat(start - eoff, seg.lens)
+                + np.arange(len(seg.vals), dtype=np.int64))
+        idx[flat] = seg.vals
+        valid[flat] = True
+    return (idx.reshape(B, num_tables, P),
+            valid.reshape(B, num_tables, P))
 
 
 @dataclasses.dataclass

@@ -88,6 +88,34 @@ def test_columnar_subset_and_chunks_are_slices():
     assert seen == len(trace)
 
 
+@pytest.mark.parametrize("qs,qe,csize", [(0, 40, 40), (14, 21, 7),
+                                           (5, 33, None), (9, 9, None)],
+                         ids=["whole", "stride", "adhoc", "empty"])
+def test_chunk_segments_regroup_table_views(qs, qe, csize):
+    """A chunk's query-major segments hold exactly what its per-table
+    views hold, in the parent's segment order within each query."""
+    trace = build_trace(dataclasses.replace(
+        ARCHETYPES["multi_tenant"], num_queries=40))
+    cq = trace.queries
+    ch = cq.chunk(qs, qe, csize)
+    seg = ch.segments()
+    assert len(seg.qid) == len(seg.tid) == len(seg.lens)
+    assert int(seg.lens.sum()) == len(seg.vals)
+    assert np.all(np.diff(seg.qid) >= 0)
+    s0 = cq.query_seg[qs]
+    np.testing.assert_array_equal(seg.tid, cq.seg_table[s0:cq.query_seg[qe]])
+    eoff = np.concatenate([[0], np.cumsum(seg.lens)])
+    by_seg = {(int(q), int(t)): seg.vals[eoff[i]:eoff[i + 1]]
+              for i, (q, t) in enumerate(zip(seg.qid, seg.tid))}
+    by_view = {}
+    for v in ch.table_views():
+        for j, q in enumerate(v.qid.tolist()):
+            by_view[(q, v.tid)] = v.vals[v.eoff[j]:v.eoff[j + 1]]
+    assert sorted(by_seg) == sorted(by_view)
+    for k, vals in by_view.items():
+        np.testing.assert_array_equal(by_seg[k], vals)
+
+
 # -- serve_trace / serve_columnar differential --------------------------------
 
 

@@ -283,3 +283,79 @@ def test_padding_counters_and_invisible_without_handle():
             np.testing.assert_array_equal(x, y)
     for k in engines[0].state:
         np.testing.assert_array_equal(engines[0].state[k], engines[1].state[k])
+
+
+def _dense_reference(chunk, table_slot, num_tables):
+    """Plain per-query packing of ``chunk.requests()``: the rule
+    ``dense_from_chunk`` follows, one request at a time."""
+    reqs = chunk.requests()
+    P = max([len(v) for r in reqs for v in r.values()] + [1])
+    P = 1 << (P - 1).bit_length()
+    idx = np.zeros((len(reqs), num_tables, P), np.int32)
+    valid = np.zeros((len(reqs), num_tables, P), bool)
+    for b, req in enumerate(reqs):
+        for tid, v in req.items():
+            idx[b, table_slot[tid], :len(v)] = v
+            valid[b, table_slot[tid], :len(v)] = True
+    return idx, valid
+
+
+def _random_requests(seed, n, tids, max_len):
+    rng = np.random.default_rng(seed)
+    return [{int(t): rng.integers(0, 1000, rng.integers(0, max_len + 1))
+             for t in rng.permutation(tids) if rng.random() < 0.7}
+            for _ in range(n)]
+
+
+def _cq(reqs):
+    from repro.core.columnar import ColumnarQueries
+    return ColumnarQueries.from_requests(reqs)
+
+
+_SORTED3 = {0: 0, 1: 1, 2: 2}
+
+
+@pytest.mark.parametrize("make,P", [
+    # ragged pooling: the longest bag (5) rounds up to 8
+    (lambda: (_cq([{0: np.array([1, 2, 3]), 2: np.array([4])},
+                   {1: np.arange(5)}]).whole(), _SORTED3), 8),
+    # every bag of one index: P = 1
+    (lambda: (_cq([{0: np.array([7])}, {1: np.array([3]),
+                                        2: np.array([0])}]).whole(),
+              _SORTED3), 1),
+    # a query that touches no table; table 3 that no query touches
+    (lambda: (_cq([{0: np.array([5, 6])}, {}, {2: np.array([9])}]).whole(),
+              {0: 0, 1: 1, 2: 2, 3: 3}), 2),
+    # zero-length segments, one of them the only segment of its query
+    (lambda: (_cq([{0: np.array([], np.int64), 1: np.array([2, 3, 4])},
+                   {2: np.array([], np.int64)}]).whole(), _SORTED3), 4),
+    # a uniform-stride chunk from the middle of a trace
+    (lambda: (_cq(_random_requests(1, 12, range(4), 6)).chunk(4, 8, 4),
+              {t: t for t in range(4)}), None),
+    # an ad-hoc [qs, qe) range
+    (lambda: (_cq(_random_requests(2, 12, range(4), 9)).chunk(3, 10),
+              {t: t for t in range(4)}), None),
+    # slots in another order than the sorted table ids
+    (lambda: (_cq(_random_requests(3, 6, [2, 5, 7], 4)).whole(),
+              {7: 0, 2: 1, 5: 2}), None),
+    # an empty chunk, alone and cut from a trace
+    (lambda: (_cq([]).whole(), _SORTED3), 1),
+    (lambda: (_cq(_random_requests(4, 6, range(3), 4)).chunk(3, 3),
+              _SORTED3), 1),
+], ids=["ragged", "p1", "untouched", "zero_len", "stride_mid", "adhoc",
+        "slot_order", "empty", "empty_range"])
+def test_dense_from_chunk_matches_per_query_packing(make, P):
+    chunk, table_slot = make()
+    T = len(table_slot)
+    idx, valid = dense_from_chunk(chunk, table_slot, T)
+    ref_idx, ref_valid = _dense_reference(chunk, table_slot, T)
+    assert idx.dtype == np.int32 and valid.dtype == bool
+    assert np.array_equal(idx, ref_idx) and np.array_equal(valid, ref_valid)
+    if P is not None:
+        assert idx.shape == (chunk.n_queries, T, P)
+
+
+def test_dense_from_chunk_rejects_unknown_table():
+    chunk = _cq([{0: np.array([1])}, {4: np.array([2, 3])}]).whole()
+    with pytest.raises(KeyError):
+        dense_from_chunk(chunk, {0: 0, 1: 1}, 2)
