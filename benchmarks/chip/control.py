@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""Readings that the limits of ``correct`` are set from, in one process.
+"""Readings that the limits of ``correct`` are set from, in one process,
+for a cell whose configuration serves ``user_bags``.
 
     python3 benchmarks/chip/control.py --workload m1.steady \\
         --seeds 1,2,3,4,5,6,7,8,9,10,11,12 --control 3 --seconds 5

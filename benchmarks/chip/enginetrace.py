@@ -15,11 +15,13 @@ This module reads them beside :mod:`tracefile`'s reduction:
 - :data:`READERS` are the per-chunk readings, each ``read(run)`` on a
   namespace with ``trace`` (a summary) and ``telemetry`` (the engine's
   handle, or None), returning None where the trace or handle lacks what it
-  reads.
+  reads. ``run.py`` builds that namespace in a traced run, and the
+  benchmark's ``metrics/<name>.py`` hand it to them.
 
 As a script it makes one traced run of a cell with the benchmark's own
-functions, with a telemetry handle attached to the engine when the window
-opens, and prints the result line with an ``engine`` entry added:
+functions and prints the result line with an ``engine`` entry added: self
+time per span, device time per scope, the idle split and the longest
+calls:
 
     python3 benchmarks/chip/enginetrace.py --workload m1.steady --seed 7 \\
         --seconds 20 [--record out.json]
@@ -345,35 +347,17 @@ def reference_pad_share(tr, served_k) -> float:
 
 def trace_cell(c, seed: int, seconds: float):
     """One traced run of resolved cell ``c`` through the benchmark's
-    ``measure`` and ``report``, with a telemetry handle attached to the
-    engine when the window opens. Returns the result line's object, with
-    the ``engine`` entry added, and the window's :func:`extract`."""
-    from types import SimpleNamespace
-
+    ``measure`` and ``report``, which attach the telemetry handle when the
+    window opens. Returns the result line's object, with the ``engine``
+    entry added, and the window's :func:`extract`."""
     import run as bench
-    from repro.obs import make_telemetry
 
-    tel = make_telemetry(True)
-    serve_window = bench.serve_window
-
-    def attached(engine, *rest):
-        # the handle goes on when the window opens, so the counters cover
-        # window chunks only
-        engine.telemetry = tel
-        return serve_window(engine, *rest)
-
-    bench.serve_window = attached
-    try:
-        m = bench.measure(c, seed, seconds, True)
-    finally:
-        bench.serve_window = serve_window
-    ex = extract(m.log_dir)
+    m = bench.measure(c, seed, seconds, True)
     out = bench.report(c, m)
-    run = SimpleNamespace(trace=EngineSummary(ex), telemetry=tel)
-    out["engine"] = engine_report(run, m.tr.backlog)
+    out["engine"] = engine_report(m.run, m.tr.backlog)
     out["engine"]["reference_pad_share"] = reference_pad_share(m.tr,
                                                                m.served_k)
-    return out, ex
+    return out, m.extract
 
 
 def main(argv=None) -> int:

@@ -1,6 +1,6 @@
 """The whole engine step's share of the chip's roofline: the least time of
-the step's useful work (``work.step``) over the device time of the step
-programs in the window, in percent."""
+the step's useful work (the served path's ``step_work``) over the device
+time of the step programs in the window, in percent."""
 import work
 
 STEP_PROGRAM = "jit_step"
@@ -12,6 +12,6 @@ def read(run):
     t = run.trace.module_seconds(STEP_PROGRAM)
     if t <= 0:
         return None
-    least, _ = work.least_seconds(work.total(work.step, run.cfg, run.counts),
-                                  run.peak)
+    least, _ = work.least_seconds(
+        work.total(run.step_work, run.cfg, run.counts), run.peak)
     return 100.0 * least / t

@@ -5,27 +5,32 @@
         --seconds 20 --trace 0
 
 The cell (``BENCHMARK.json``) names a configuration file and a traffic mix
-file; nothing in this file belongs to one cell. One process:
+file; the configuration names its served path (``"served"``, by default
+``user_bags``), a module ``served/<name>.py`` that builds the engine, turns
+traffic into the program's inputs, makes the one timed call and checks its
+output against the plain reference. Nothing in this file belongs to one
+cell or one kind of query. One process:
 
-1. makes the run's traffic from the seed (``traffic.py``) and the tables on
-   the device (``tables.py``), and hands the tables to
-   ``DeviceServingEngine``;
+1. makes the run's traffic from the seed (``traffic.py``), and the engine
+   and the program's input of each chunk through the served path;
 2. warms up by serving the leading chunks of the same traffic untimed, plus
-   one chunk of each ``[B, T, P]`` shape the window holds that the warm-up
-   did not (set-up ends here: ``setup_s``);
-3. serves the window open loop, one chunk per ``serve_columnar`` call. A
-   chunk is dispatched once its last query has arrived, or at once when the
-   engine has fallen behind; a query's latency runs from its arrival to the
-   moment its chunk's pooled bags are on the host. A fixed-rate window
-   serves every query due in it, however long after the close; a backlog
-   window has every query due at its opening and counts what completed by
-   its close;
-4. checks what the window served against the plain reference
-   (``reference.py``) and prints the result as the last line of stdout.
+   one chunk of each compile shape the window holds that the warm-up did
+   not (set-up ends here: ``setup_s``);
+3. serves the window open loop, one chunk per timed call. A chunk is
+   dispatched once its last query has arrived, or at once when the engine
+   has fallen behind; a query's latency runs from its arrival to the
+   moment its chunk's output is on the host. A fixed-rate window serves
+   every query due in it, however long after the close; a backlog window
+   has every query due at its opening and counts what completed by its
+   close;
+4. checks what the window served with the served path's ``check`` and
+   prints the result as the last line of stdout.
 
-With ``--trace 1`` the window runs under the profiler and the line carries
-the cell's per-layer metrics, read by ``metrics/<name>.py`` (a name with a
-``.variant`` suffix falls back to the reader of its base name).
+With ``--trace 1`` the window runs under the profiler with a telemetry
+handle on the engine, and the line carries the cell's per-layer metrics,
+read by ``metrics/<name>.py`` (a name with a ``.variant`` suffix falls back
+to the reader of its base name) from the trace, the engine's spans and
+counters, and the run's counts.
 
 With no TPU, or fewer chips than the cell asks for, it exits non-zero and
 prints no result.
@@ -48,19 +53,21 @@ import numpy as np  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
+SERVED_DIR = os.path.join(HERE, "served")
 sys.path.insert(0, HERE)
 sys.path.insert(1, os.path.join(ROOT, "src"))
 
+import enginetrace  # noqa: E402
 import peaks as peaks_mod  # noqa: E402
-import reference  # noqa: E402
-import tables  # noqa: E402
 import tracefile  # noqa: E402
 import traffic as traffic_mod  # noqa: E402
 
-POOLED_SAMPLE_CHUNKS = 32     # window chunks whose pooled bags are checked
+DEFAULT_SERVED = "user_bags"  # served path of a configuration that names none
+OUTPUT_SAMPLE_CHUNKS = 32     # window chunks whose output is checked
 DRAIN_LIMIT_S = 60.0          # a window query unanswered this long after
                               # the close counts as failed
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 class NoAccelerator(RuntimeError):
@@ -76,10 +83,29 @@ def load_json(path: str):
         return json.load(f)
 
 
+def load_module(path: str, name: str):
+    """The Python file ``path`` as a module named ``name``."""
+    if not os.path.exists(path):
+        raise FileNotFoundError(path)
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def served_module(name: str, served_dir: str = SERVED_DIR):
+    """The served-path module ``<served_dir>/<name>.py``."""
+    return load_module(os.path.join(served_dir, name + ".py"),
+                       "chip_served_" + name)
+
+
 def resolve(bench: dict, workload: str,
-            traffic_dir: str = os.path.join(HERE, "traffic")):
-    """The cell ``workload`` of ``bench`` with its configuration, mix and
-    metric entries. Mixes are ``<traffic_dir>/<traffic>.json``."""
+            traffic_dir: str = os.path.join(HERE, "traffic"),
+            served_dir: str = SERVED_DIR):
+    """The cell ``workload`` of ``bench`` with its configuration, mix,
+    served path and metric entries. Mixes are
+    ``<traffic_dir>/<traffic>.json``, served paths
+    ``<served_dir>/<served>.py``."""
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
@@ -87,6 +113,7 @@ def resolve(bench: dict, workload: str,
     conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     cfg = load_json(os.path.join(ROOT, conf["file"]))
     mix = load_json(os.path.join(traffic_dir, cell["traffic"] + ".json"))
+    served = served_module(cfg.get("served", DEFAULT_SERVED), served_dir)
     e2e = [m for m in bench["end_to_end"]
            if workload in m.get("workloads", [workload])]
     names = {m["name"] for m in e2e}
@@ -94,7 +121,7 @@ def resolve(bench: dict, workload: str,
              if workload in m.get("workloads", [workload] if m["moves"] in names
                                   else [])]
     return SimpleNamespace(name=workload, cell=cell, cfg=cfg, mix=mix,
-                           e2e=e2e, layer=layer)
+                           served=served, e2e=e2e, layer=layer)
 
 
 def reader(name: str):
@@ -102,11 +129,8 @@ def reader(name: str):
     for stem in dict.fromkeys((name, name.split(".")[0])):
         path = os.path.join(HERE, "metrics", stem + ".py")
         if os.path.exists(path):
-            spec = importlib.util.spec_from_file_location(
-                "chip_metric_" + stem.replace(".", "_"), path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return load_module(
+                path, "chip_metric_" + stem.replace(".", "_")).read
     raise FileNotFoundError(f"no reader for metric {name!r}")
 
 
@@ -132,32 +156,6 @@ def use_compile_cache() -> str:
     return path
 
 
-def build_engine(cfg: dict, seed: int):
-    from repro.core.io_sim import DEVICES
-    from repro.runtime.engine import DeviceServingEngine, EngineConfig
-    tabs = tables.device_tables(seed, cfg["tables"]["rows"], cfg["dim"])
-    engine = DeviceServingEngine(
-        tabs, DEVICES[cfg["sm_device"]],
-        EngineConfig(hbm_cache_bytes=cfg["hbm_cache_bytes"],
-                     ways=cfg["cache_ways"]))
-    del tabs
-    geo = engine.cache.geo
-    if (geo.num_sets, geo.ways) != (cfg["cache_sets"], cfg["cache_ways"]):
-        raise ValueError(f"engine cache {geo.num_sets} sets x {geo.ways} "
-                         f"ways, configuration {cfg['cache_sets']} x "
-                         f"{cfg['cache_ways']}")
-    return engine
-
-
-def program_chunks(tr):
-    """The traffic as the program's columnar chunks."""
-    from repro.core.columnar import ColumnarQueries
-    cq = ColumnarQueries(tr.values, tr.seg_offsets, tr.seg_table,
-                         tr.query_seg)
-    B = tr.chunk
-    return [cq.chunk(s, s + B, B) for s in range(0, tr.n_queries, B)]
-
-
 class GcPauses:
     """Collections of the garbage collector, and their seconds, while
     ``on`` is set."""
@@ -181,44 +179,49 @@ class GcPauses:
 
 
 class CompileCounter:
-    """Counts XLA compilations (or loads from the persistent cache) while
-    ``on`` is set."""
+    """Counts XLA compilations (or loads from the persistent cache), and
+    the loads among them, while ``on`` is set."""
 
     def __init__(self):
         import jax
-        self.on, self.count = False, 0
+        self.on, self.count, self.cached = False, 0, 0
         jax.monitoring.register_event_duration_secs_listener(self._event)
+        jax.monitoring.register_event_listener(self._hit)
 
     def _event(self, event, duration, **kw):
         if self.on and event == COMPILE_EVENT:
             self.count += 1
 
+    def _hit(self, event, **kw):
+        if self.on and event == CACHE_HIT_EVENT:
+            self.cached += 1
 
-def warm_up(engine, chunks, tr):
-    """Serve the warm-up chunks, then one window chunk of each padded
-    pooling the warm-up lacked. Returns the served chunk indices and the
-    program's reads of each."""
-    B = tr.chunk
-    first = tr.warmup // B
+
+def warm_up(engine, xs, tr, served):
+    """Serve the warm-up chunks, then one window chunk of each compile
+    shape (``served.shape``) the warm-up lacked. Returns the served chunk
+    indices and the program's reads of each."""
+    first = tr.warmup // tr.chunk
     order = list(range(first))
-    have = {traffic_mod.padded_pooling(tr, k * B, k * B + B) for k in order}
-    for k in range(first, len(chunks)):
-        p = traffic_mod.padded_pooling(tr, k * B, k * B + B)
-        if p not in have:
-            have.add(p)
+    have = {served.shape(tr, k) for k in order}
+    for k in range(first, len(xs)):
+        key = served.shape(tr, k)
+        if key not in have:
+            have.add(key)
             order.append(k)
-    reads = [engine.serve_columnar(chunks[k])[2] for k in order]
+    reads = [served.serve(engine, xs[k])[1] for k in order]
     return order, reads
 
 
-def serve_window(engine, chunks, tr, seconds, rng):
-    """The timed window. Returns per window chunk its start and end (s after
-    the window opened, NaN where unserved), the program's reads, and a
-    uniform sample of served chunks with their pooled bags."""
+def serve_window(engine, xs, tr, seconds, rng, serve):
+    """The timed window, one ``serve(engine, x)`` call per chunk. Returns
+    per window chunk its start and end (s after the window opened, NaN
+    where unserved), the program's reads, and a uniform sample of served
+    chunks with their output."""
     import jax
     ann = jax.profiler.TraceAnnotation
     first = tr.warmup // tr.chunk
-    win = chunks[first:]
+    win = xs[first:]
     n = len(win)
     ready = (np.zeros(n) if tr.backlog else traffic_mod.chunk_ready_s(tr))
     start, done = np.full(n, np.nan), np.full(n, np.nan)
@@ -234,70 +237,60 @@ def serve_window(engine, chunks, tr, seconds, rng):
                     time.sleep(ready[k] - now)
             start[k] = time.perf_counter() - t0
             with ann(tracefile.CHUNK_SPAN):
-                pooled, _, io = engine.serve_columnar(win[k])
+                output, io = serve(engine, win[k])
             done[k] = time.perf_counter() - t0
             reads[k] = io
-            # reservoir sample of the served chunks' pooled bags
-            if len(sample) < POOLED_SAMPLE_CHUNKS:
-                sample.append((k, pooled))
+            # reservoir sample of the served chunks' output
+            if len(sample) < OUTPUT_SAMPLE_CHUNKS:
+                sample.append((k, output))
             else:
                 j = int(rng.integers(len(reads)))
-                if j < POOLED_SAMPLE_CHUNKS:
-                    sample[j] = (k, pooled)
+                if j < OUTPUT_SAMPLE_CHUNKS:
+                    sample[j] = (k, output)
     if tr.backlog and np.isfinite(done[-1]) and done[-1] < seconds:
         raise RanOut(f"served all {n * tr.chunk} pre-made queries "
                      f"{seconds - done[-1]:.3f} s before the window closed")
     return start, done, reads, sample
 
 
-def check(cfg, tr, seed, served, prog_reads, sample):
-    """Compare what was served with the reference. Returns the checks and
-    the per-serving counts of useful work."""
-    want, counts = reference.expected_reads(cfg, tr, served)
-    mismatch = sum(int(np.sum(np.asarray(p) != w))
-                   for p, w in zip(prog_reads, want))
-    B, T, D = tr.chunk, tr.lens.shape[1], cfg["dim"]
-    first = tr.warmup // B
-    offsets = tables.row_offsets(cfg["tables"]["rows"])
-    gap = 0.0
-    for k, pooled in sample:
-        q0 = (first + k) * B
-        _, t, r, starts = reference.chunk_lookups(tr, q0, q0 + B)
-        ref = reference.pool(seed, offsets, t, r, starts, B * T, D)
-        gap = max(gap, float(np.abs(pooled.reshape(B * T, D) - ref).max()))
-    return ({"pooled_gap": (gap, reference.POOLED_GAP_LIMIT),
-             "sm_ios_mismatch": (mismatch, reference.SM_IOS_MISMATCH_LIMIT)},
-            counts)
-
-
 def measure(c, seed: int, seconds: float, trace: bool,
             t_start: float = T_START) -> SimpleNamespace:
     """Set up, warm up and serve one window of resolved cell ``c``; the
     engine is freed before this returns. ``setup_s`` runs from
-    ``t_start``."""
+    ``t_start``. ``m.counters`` holds how far the served path's counters
+    moved over the window. Traced, the served path attaches a telemetry
+    handle (``m.telemetry``) to the engine as the window opens, so the
+    handle's counters cover window chunks only."""
     import jax
     phases = [("start", time.perf_counter())]
     dev = accelerators(c.cell["chips"])[0]
     m = SimpleNamespace(dev=dev, peak=peaks_mod.peaks(dev.device_kind),
                         cache_dir=use_compile_cache(), seed=seed,
-                        seconds=seconds, log_dir=None)
+                        seconds=seconds, log_dir=None, telemetry=None)
     compiles = CompileCounter()
     phases.append(("devices", time.perf_counter()))
     m.tr = traffic_mod.generate(c.cfg, c.mix, seed, seconds)
     phases.append(("traffic", time.perf_counter()))
-    engine = build_engine(c.cfg, seed)
+    compiles.on = True
+    engine = c.served.build(c.cfg, seed)
     phases.append(("tables and engine", time.perf_counter()))
-    chunks = program_chunks(m.tr)
-    m.warm_order, m.warm_reads = warm_up(engine, chunks, m.tr)
+    xs = c.served.inputs(c.cfg, m.tr, seed)
+    m.warm_order, m.warm_reads = warm_up(engine, xs, m.tr, c.served)
+    compiles.on = False
+    m.setup_programs = (compiles.count, compiles.cached)
+    compiles.count = compiles.cached = 0
     phases.append(("warm-up", time.perf_counter()))
     m.phases = [(name, t1 - t0) for (_, t0), (name, t1)
                 in zip(phases, phases[1:])]
     rng = np.random.default_rng(traffic_mod.seed_words(seed) + [3])
     if trace:
+        from repro.obs import make_telemetry
         m.log_dir = tempfile.mkdtemp(prefix="chip_trace_")
         jax.profiler.start_trace(m.log_dir)
-    hits0, misses0 = int(engine.state["hits"]), int(engine.state["misses"])
-    # objects made in set-up (traffic, chunks, engine) are moved out of the
+        m.telemetry = make_telemetry(True)
+        c.served.attach(engine, m.telemetry)
+    before = c.served.counters(engine)
+    # objects made in set-up (traffic, inputs, engine) are moved out of the
     # collector's reach, so the window's collections walk only its own
     gc.collect()
     gc.freeze()
@@ -305,17 +298,17 @@ def measure(c, seed: int, seconds: float, trace: bool,
     pauses = GcPauses()
     compiles.on = pauses.on = True
     m.start, m.done, m.win_reads, m.sample = serve_window(
-        engine, chunks, m.tr, seconds, rng)
+        engine, xs, m.tr, seconds, rng, c.served.serve)
     compiles.on = pauses.on = False
     pauses.close()
     gc.unfreeze()
     m.compiles, m.gc = compiles.count, pauses
-    m.hits = int(engine.state["hits"]) - hits0
-    m.misses = int(engine.state["misses"]) - misses0
+    m.counters = {k: v - before[k]
+                  for k, v in c.served.counters(engine).items()}
     if trace:
         jax.profiler.stop_trace()
     m.mem = (dev.memory_stats() or {}).get("peak_bytes_in_use")
-    del engine, chunks
+    del engine, xs
     gc.collect()
     B = m.tr.chunk
     first = m.tr.warmup // B
@@ -328,10 +321,13 @@ def measure(c, seed: int, seconds: float, trace: bool,
 
 def report(c, m) -> dict:
     """Check a measured run against the reference and build the result
-    line's object; prints what was compared on stderr."""
+    line's object; prints what was compared on stderr. The readers'
+    namespace is left in ``m.run``, and a traced window's
+    :func:`enginetrace.extract` in ``m.extract``."""
     cfg, tr, B = c.cfg, m.tr, m.tr.chunk
     t_ref = time.perf_counter()
-    checks, counts = check(cfg, tr, m.seed, m.served, m.prog_reads, m.sample)
+    checks, counts = c.served.check(cfg, tr, m.seed, m.served, m.prog_reads,
+                                    m.sample)
     t_ref = time.perf_counter() - t_ref
     start, done, seconds = m.start, m.done, m.seconds
     n_win = len(done)
@@ -350,8 +346,9 @@ def report(c, m) -> dict:
             f"{len(m.warm_order)} chunks, window {len(m.served_k)} of "
             f"{n_win} chunks served",
             f"set-up {m.setup_s:.3f} s (" + ", ".join(
-                f"{k} {v:.3f}" for k, v in m.phases) + "), window compiles "
-            f"{m.compiles}, reference {t_ref:.3f} s",
+                f"{k} {v:.3f}" for k, v in m.phases) + "), set-up programs "
+            f"{m.setup_programs[0]} ({m.setup_programs[1]} from the compile "
+            f"cache), window compiles {m.compiles}, reference {t_ref:.3f} s",
             f"window gc: {m.gc.count} collections, {m.gc.seconds:.4f} s, "
             f"longest {m.gc.longest:.4f} s"]
     if not tr.backlog:
@@ -372,13 +369,14 @@ def report(c, m) -> dict:
     serve_ms = 1e3 * (done - start)[served]
     info.append("dispatch lag ms (start - ready): " + " ".join(
         f"{k} {v:.3f}" for k, v in lag.items()))
-    info.append(f"serve_columnar ms: median {np.median(serve_ms):.3f} mean "
+    info.append(f"serve call ms: median {np.median(serve_ms):.3f} mean "
                 f"{serve_ms.mean():.3f} longest "
                 + " ".join(f"{v:.1f}" for v in np.sort(serve_ms)[-5:][::-1]))
 
-    run = SimpleNamespace(
-        cfg=cfg, peak=m.peak, counts=counts[len(m.warm_order):], trace=None,
-        hits=m.hits, misses=m.misses,
+    m.run = run = SimpleNamespace(
+        cfg=cfg, peak=m.peak, counts=counts[len(m.warm_order):],
+        step_work=c.served.step_work, trace=None, telemetry=m.telemetry,
+        **m.counters,
         window_reads=sum(int(np.sum(m.win_reads[k])) for k in m.served_k),
         window_queries=B * len(m.served_k))
     device = {"platform": m.dev.platform, "kind": m.dev.device_kind,
@@ -387,7 +385,8 @@ def report(c, m) -> dict:
            "attempted": B * (len(m.served_k) if tr.backlog else n_win),
            "failed": unanswered * B}
     if m.log_dir:
-        run.trace = tracefile.Summary(tracefile.extract(m.log_dir))
+        m.extract = enginetrace.extract(m.log_dir)
+        run.trace = enginetrace.EngineSummary(m.extract)
         shutil.rmtree(m.log_dir, ignore_errors=True)
         out["metrics"] = {}
         for spec in c.layer:

@@ -18,7 +18,8 @@ TINY = os.path.join(DATA, "configs", "tiny.json")
 
 def tiny_bench(config_file: str = TINY) -> dict:
     """A BENCHMARK.json-shaped dict of tiny cells, one per test mix."""
-    cells = [("tiny.steady", "steady"), ("tiny.backlog", "backlog")]
+    cells = [("tiny.steady", "steady"), ("tiny.backlog", "backlog"),
+             ("tiny.drift_backlog", "drift_backlog")]
     return {
         "configs": [{"name": "tiny", "file": config_file}],
         "workloads": [{"name": n, "config": "tiny", "traffic": t,

@@ -1,5 +1,7 @@
 """``BENCHMARK.json`` keeps the benchmark's format, and every cell finds
-its configuration, its mix and a reader for each of its metrics by name."""
+its configuration, its mix, its served path and a reader for each of its
+metrics by name."""
+import ast
 import json
 import os
 import re
@@ -122,3 +124,39 @@ def test_metrics_and_readers(bench):
         assert any(c in m["workloads"] for m in layer)
         assert all(m["name"] in {x["name"] for x in e2e}
                    for m in run.resolve(bench, c).e2e)
+
+
+SERVED_API = ("build", "inputs", "shape", "serve", "counters", "attach",
+              "check", "step_work")
+
+
+def test_every_configuration_names_a_served_path(bench):
+    import run
+    for c in bench["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            name = json.load(f).get("served", run.DEFAULT_SERVED)
+        assert NAME.match(name)
+        assert os.path.exists(os.path.join(CHIP, "served", name + ".py"))
+        mod = run.served_module(name)
+        assert all(callable(getattr(mod, fn)) for fn in SERVED_API)
+
+
+def test_harness_imports_no_served_path():
+    """What belongs to one kind of query lives in ``served/``: the harness
+    imports neither the engine nor the reference, and touches no attribute
+    of the engine it is handed."""
+    with open(os.path.join(CHIP, "run.py")) as f:
+        tree = ast.parse(f.read())
+    imported, touched = set(), set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported.add(node.module)
+        elif (isinstance(node, (ast.Attribute, ast.Subscript))
+              and isinstance(node.value, ast.Name)
+              and node.value.id == "engine"):
+            touched.add(ast.unparse(node))
+    assert not {m for m in imported
+                if m == "reference" or m.startswith("repro.runtime")}
+    assert not touched

@@ -166,13 +166,46 @@ def test_traced_tiny_run_reads_engine(monkeypatch):
                     traffic_dir=os.path.join(DATA, "traffic"))
     out, ex = enginetrace.trace_cell(c, 2 ** 33 + 7, 0.5)
     assert out["correct"] is True, out["checks"]
-    assert run.serve_window.__module__ == "run"     # the hook is undone
     eng = out["engine"]
     spans = [n for n, *_ in ex["spans"]]
     assert spans.count(names.SPAN_SERVE) == eng["chunks"] > 0
     assert eng["counters"]["engine.batches"] == eng["chunks"]
     assert eng["metrics"]["pad_share.sat"] == eng["reference_pad_share"]
     assert 0 < eng["reference_pad_share"] < 100
+
+
+ENGINE_METRICS = ["pack_ms_per_chunk", "account_ms_per_chunk",
+                  "probe_ms_per_chunk", "fill_ms_per_chunk", "pad_share"]
+
+
+@pytest.mark.parametrize("workload,suffix", [("tiny.steady", ""),
+                                             ("tiny.backlog", ".sat")])
+def test_traced_tiny_run_line_carries_engine_metrics(monkeypatch, workload,
+                                                     suffix):
+    """The benchmark's own traced run hands the readers the engine's spans
+    and counters: its line carries the host-span readings and the padded
+    share, equal to the reference's exactly. The CPU's trace has no device
+    plane, so the scope readings read nothing and are left out."""
+    run = on_cpu(monkeypatch)
+    bench = tiny_bench()
+    bench["per_layer"] += [{"name": n + suffix, "unit": "x",
+                            "moves": "queries_per_s", "workloads": [workload]}
+                           for n in ENGINE_METRICS]
+    c = run.resolve(bench, workload,
+                    traffic_dir=os.path.join(DATA, "traffic"))
+    m = run.measure(c, 2 ** 33 + 9, 0.5, True)
+    out = run.report(c, m)
+    assert out["correct"] is True, out["checks"]
+    got = {k: v["value"] for k, v in out["metrics"].items()}
+    assert {"pack_ms_per_chunk" + suffix, "account_ms_per_chunk" + suffix,
+            "pad_share" + suffix} <= set(got)
+    assert not {"probe_ms_per_chunk" + suffix,
+                "fill_ms_per_chunk" + suffix} & set(got)
+    assert got["pack_ms_per_chunk" + suffix] > 0
+    assert got["pad_share" + suffix] == enginetrace.reference_pad_share(
+        m.tr, m.served_k)
+    # the handle went on at the window's opening: one batch per window chunk
+    assert m.telemetry.registry.counters["engine.batches"] == len(m.served_k)
 
 
 def test_metric_names_follow_the_cell():

@@ -1,7 +1,7 @@
 """End-to-end runs of the chip benchmark's harness on the CPU, with the
 Pallas kernels interpreted: the printed last line, the exit without a TPU,
-a mix added as a file alone, and faults of the timed path that ``correct``
-has to catch."""
+a mix and a served path added as a file alone, and faults of the timed
+path that ``correct`` has to catch."""
 import functools
 import json
 import os
@@ -10,7 +10,7 @@ import sys
 
 import pytest
 
-from chip_bench_testlib import CHIP, DATA, ROOT, on_cpu, tiny_bench
+from chip_bench_testlib import CHIP, DATA, ROOT, TINY, on_cpu, tiny_bench
 
 SEED = 2 ** 33 + 5      # larger than 32 signed bits hold
 SECONDS = 0.5
@@ -22,12 +22,13 @@ def run_mod(monkeypatch):
 
 
 def run_main(run, monkeypatch, tmp_path, workload, trace, bench=None,
-             traffic_dir=os.path.join(DATA, "traffic")):
+             traffic_dir=os.path.join(DATA, "traffic"), served_dir=None):
     """``run.main`` on a tiny BENCHMARK.json; returns (rc, last line)."""
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench or tiny_bench()))
     monkeypatch.setattr(run, "ROOT", str(tmp_path))
     monkeypatch.setattr(run, "resolve", functools.partial(
-        run.resolve, traffic_dir=traffic_dir))
+        run.resolve, traffic_dir=traffic_dir,
+        served_dir=served_dir or run.SERVED_DIR))
     import io
     import contextlib
     buf = io.StringIO()
@@ -43,6 +44,7 @@ def run_main(run, monkeypatch, tmp_path, workload, trace, bench=None,
                         "setup_s"}),
     ("tiny.backlog", 0, {"queries_per_s", "setup_s"}),
     ("tiny.steady", 1, {"cache_hit_rate"}),
+    ("tiny.drift_backlog", 0, {"queries_per_s", "setup_s"}),
 ])
 def test_tiny_run_prints_result_line(run_mod, monkeypatch, tmp_path,
                                      workload, trace, metrics):
@@ -94,6 +96,27 @@ def test_mix_added_as_a_file_runs(run_mod, monkeypatch, tmp_path):
     assert set(out["metrics"]) == {"queries_per_s", "setup_s"}
 
 
+@pytest.mark.parametrize("check_scale,correct", [(2.0, True), (1.0, False)])
+def test_served_path_added_as_a_file_runs(run_mod, monkeypatch, tmp_path,
+                                          check_scale, correct):
+    """A configuration names a served-path module that lies in a directory
+    of its own; the harness builds, warms up, serves and checks through it
+    with no edit, and its ``check`` decides ``correct``."""
+    with open(TINY) as f:
+        cfg = json.load(f)
+    cfg.update(served="scaled_bags", output_scale=2.0,
+               check_scale=check_scale)
+    path = tmp_path / "scaled.json"
+    path.write_text(json.dumps(cfg))
+    rc, out = run_main(run_mod, monkeypatch, tmp_path, "tiny.steady", 0,
+                       bench=tiny_bench(str(path)),
+                       served_dir=os.path.join(DATA, "served"))
+    assert rc == 0 and out["correct"] is correct, out["checks"]
+    gap = out["checks"]["pooled_gap"]
+    assert (gap["value"] <= gap["limit"]) is correct
+    assert out["checks"]["sm_ios_mismatch"]["value"] == 0
+
+
 def _broken_step(kind):
     """A ``_make_step`` whose step has the fault ``kind``."""
     from repro.runtime.engine import DeviceServingEngine
@@ -115,16 +138,17 @@ def _broken_step(kind):
     return make
 
 
-@pytest.mark.parametrize("kind,caught_by", [
-    ("state_unchanged", "sm_ios_mismatch"),
-    ("half_batch", "pooled_gap"),
-    ("answer_altered", "pooled_gap"),
-])
+@pytest.mark.parametrize("kind,caught_by,workload", [
+    pytest.param(kind, by, w, id=f"{kind}-{by}{suffix}")
+    for w, suffix in (("tiny.steady", ""), ("tiny.drift_backlog", "-drift"))
+    for kind, by in (("state_unchanged", "sm_ios_mismatch"),
+                     ("half_batch", "pooled_gap"),
+                     ("answer_altered", "pooled_gap"))])
 def test_broken_timed_path_is_not_correct(run_mod, monkeypatch, tmp_path,
-                                          kind, caught_by):
+                                          kind, caught_by, workload):
     from repro.runtime.engine import DeviceServingEngine
     monkeypatch.setattr(DeviceServingEngine, "_make_step", _broken_step(kind))
-    rc, out = run_main(run_mod, monkeypatch, tmp_path, "tiny.steady", 0)
+    rc, out = run_main(run_mod, monkeypatch, tmp_path, workload, 0)
     assert rc == 0
     assert out["correct"] is False
     chk = out["checks"][caught_by]

@@ -10,6 +10,7 @@ import pytest
 from chip_bench_testlib import DATA
 import run
 import tracefile
+import work
 
 DEV = "/device:TPU:0"
 MS = 1_000_000      # ns
@@ -57,6 +58,7 @@ def readers_on(ex, counts):
     cfg = {"dim": 128, "row_header_bytes": 8, "cache_ways": 8}
     peak = {"hbm_bytes_per_s": 819e9, "flops_per_s": 197e12}
     r = SimpleNamespace(cfg=cfg, peak=peak, counts=counts,
+                        step_work=work.step,
                         trace=tracefile.Summary(ex), hits=1, misses=1,
                         window_reads=1, window_queries=1)
     names = ["host_ms_per_chunk", "step_mfu", "gather_pool_roofline",
