@@ -11,10 +11,12 @@ Inference batching matches §2.2: user embeddings are looked up once per query
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.layers import dense_init, logical_constraint
 
@@ -56,9 +58,9 @@ def _init_mlp(key, dims, dtype):
              "b": jnp.zeros((dims[i + 1],), dtype)} for i in range(len(dims) - 1)]
 
 
-def _mlp(layers, x, final_act=False):
+def _mlp(layers, x, final_act=False, precision=None):
     for i, l in enumerate(layers):
-        x = x @ l["w"] + l["b"]
+        x = jnp.matmul(x, l["w"], precision=precision) + l["b"]
         if i < len(layers) - 1 or final_act:
             x = jax.nn.relu(x)
     return x
@@ -89,14 +91,53 @@ def embed_bags(tables, indices: jax.Array) -> jax.Array:
     return jnp.stack(pooled, axis=1)                  # [B, T, E]
 
 
-def interact(z0: jax.Array, emb: jax.Array) -> jax.Array:
+def interact(z0: jax.Array, emb: jax.Array, precision=None) -> jax.Array:
     """Dot-product interaction: z0 [B, E], emb [B, T, E] -> [B, E + T(T+1)/2]."""
     feats = jnp.concatenate([z0[:, None, :], emb], axis=1)   # [B, F, E]
-    gram = jnp.einsum("bfe,bge->bfg", feats, feats)
+    gram = jnp.einsum("bfe,bge->bfg", feats, feats, precision=precision)
     F = feats.shape[1]
     iu, ju = jnp.triu_indices(F, k=1)
     pairs = gram[:, iu, ju]                                   # [B, F(F-1)/2]
     return jnp.concatenate([z0, pairs], axis=1)
+
+
+def dense_half_dims(num_dense: int, bottom: Sequence[int],
+                    top: Sequence[int], num_bags: int):
+    """Layer widths ``(bottom dims, top dims)`` of the dense half, inputs
+    first: the bottom MLP maps ``num_dense`` features to the embedding
+    width ``bottom[-1]``; the top MLP takes ``z0`` and the upper triangle of
+    the Gram matrix of ``[z0, bags]`` (``interact``)."""
+    f = num_bags + 1
+    return ([num_dense, *bottom], [bottom[-1] + f * (f - 1) // 2, *top])
+
+
+def init_dense_half(seed, num_dense: int, bottom: Sequence[int],
+                    top: Sequence[int], num_bags: int) -> dict:
+    """Float32 weights of the dense half, drawn on the host from ``seed``
+    (anything ``np.random.default_rng`` takes): layer by layer, bottom
+    first, ``w ~ N(0, 2 / fan_in)`` (He, for the ReLU layers) then
+    ``b ~ N(0, 0.01^2)``."""
+    rng = np.random.default_rng(seed)
+    out = {}
+    for part, dims in zip(("bottom", "top"), dense_half_dims(
+            num_dense, bottom, top, num_bags)):
+        out[part] = []
+        for fi, fo in zip(dims[:-1], dims[1:]):
+            w = rng.standard_normal((fi, fo), np.float32)
+            w *= np.float32(math.sqrt(2.0 / fi))
+            b = rng.standard_normal(fo, np.float32) * np.float32(0.01)
+            out[part].append({"w": w, "b": b})
+    return out
+
+
+def dense_logits(params: dict, dense: jax.Array, emb: jax.Array,
+                 precision=None) -> jax.Array:
+    """The dense half: dense [N, num_dense] and pooled bags emb [N, T, E]
+    -> logits [N]. ``precision`` goes to every matmul (``lax.Precision``
+    or its name)."""
+    z0 = _mlp(params["bottom"], dense, final_act=True, precision=precision)
+    x = interact(z0, emb, precision=precision)
+    return _mlp(params["top"], x, precision=precision)[:, 0]
 
 
 def forward(params: dict, batch: dict, arch: DLRMArch) -> jax.Array:
@@ -141,6 +182,4 @@ def score_items(params: dict, user_emb: jax.Array, item_idx: jax.Array,
     user_emb = jnp.broadcast_to(user_emb[None], (Bi,) + user_emb.shape)
     item_emb = embed_bags(params["tables"][n_user:], item_idx)              # [Bi, Ti, E]
     emb = jnp.concatenate([user_emb, item_emb], axis=1)
-    z0 = _mlp(params["bottom"], dense, final_act=True)
-    x = interact(z0, emb)
-    return jax.nn.sigmoid(_mlp(params["top"], x)[:, 0])
+    return jax.nn.sigmoid(dense_logits(params, dense, emb))

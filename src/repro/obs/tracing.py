@@ -35,8 +35,9 @@ SPAN_VALIDATE = "engine.validate"    # shape and row-range checks of the block
 SPAN_DISPATCH = "engine.dispatch"    # host-to-device copies + the async step call
 SPAN_FETCH = "engine.fetch"          # wait for the step, copy results back
 SPAN_ACCOUNT = "engine.account"      # IO accounting, per-query result arrays
+SPAN_ITEM_PACK = "engine.item_pack"  # rank_columnar: item CSR -> [N, Ti, P] block
 ENGINE_SPANS = (SPAN_SERVE, SPAN_PACK, SPAN_VALIDATE, SPAN_DISPATCH,
-                SPAN_FETCH, SPAN_ACCOUNT)
+                SPAN_FETCH, SPAN_ACCOUNT, SPAN_ITEM_PACK)
 
 SCOPE_PROBE = "engine.probe"         # set index, probe kernel, stamp update
 SCOPE_GATHER = "engine.gather"       # hit-side pool, gather-pool of misses
@@ -49,8 +50,17 @@ SCOPE_STAMP = "cache.stamp"          # LRU stamp scatter, hit/miss counts
 SCOPE_RANK = "cache.rank"            # insert: tag compare, rank within set
 SCOPE_LRU = "cache.lru"              # insert: LRU order of each set's ways
 SCOPE_SCATTER = "cache.scatter"      # insert: tag, data and stamp scatters
+
+# the ranking step's regions beside the user step's (rank_columnar)
+SCOPE_ITEM = "engine.item"           # item-store gather-pool, scale and bias gathers
+SCOPE_DENSE = "engine.dense"         # Eq. 2 broadcast, bottom MLP, interaction, top MLP
+RANK_SCOPES = (SCOPE_ITEM, SCOPE_DENSE)
+
+# A trace reader knows ENGINE_SCOPES + CACHE_SCOPES. ENGINE_SCOPES stays the
+# four regions that every step has (a recorded trace of the user step pins
+# that set), so the ranking step's regions ride with the cache's parts.
 CACHE_SCOPES = (SCOPE_REMATCH, SCOPE_STAMP, SCOPE_RANK, SCOPE_LRU,
-                SCOPE_SCATTER)
+                SCOPE_SCATTER) + RANK_SCOPES
 
 # Event tuples: (ts_us, dur_us, ph, name, cat, pid_label, args)
 _PH_SPAN = "X"

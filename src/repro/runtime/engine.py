@@ -29,11 +29,22 @@ exactly where a sequential run would take the miss before the fill makes
 every later occurrence a hit — and fill the cache once (duplicates are
 masked out of ``cache.insert`` so one scatter can't double-fill an LRU set).
 
+Ranking (§2.2, Eq. 2): given item tables and dense-half weights the engine
+also holds an FM-direct item store (8-bit rows resident in HBM, no row
+cache, no SM reads; §4.6) and ``rank_columnar`` runs one jitted program:
+the user step above, the item gather-pool, the broadcast of each query's
+pooled user bags over its candidates, and the dense half
+(``models.dlrm.dense_logits``: bottom MLP, dot interaction, top MLP), then
+the sigmoid. SM reads and Eq. 3 accounting come from the user side alone.
+
 Instrumentation, on the JAX profiler's clock: the host path's steps are
 ``jax.profiler.TraceAnnotation`` spans and the step's regions are
 ``jax.named_scope``s, both named in ``repro.obs.tracing``; with a telemetry
 handle attached, each step adds its block's positions and valid positions
-to ``engine.positions`` and ``engine.valid_positions``.
+to ``engine.positions`` and ``engine.valid_positions``, and each ranking
+step its item block's to ``engine.item_positions`` and
+``engine.item_valid_positions`` and its candidates to
+``engine.items_scored``.
 """
 from __future__ import annotations
 
@@ -50,6 +61,7 @@ from repro.core.io_sim import DeviceModel, IOEngine, IOQueueConfig
 from repro.core.quant import quantize_rows, row_bytes
 from repro.core.sdm import QueryStats
 from repro.kernels import ops
+from repro.models import dlrm
 from repro.obs import tracing as names
 
 TraceAnnotation = jax.profiler.TraceAnnotation
@@ -105,6 +117,32 @@ def dense_from_chunk(chunk: ColumnarChunk, table_slot: Dict[int, int],
             valid.reshape(B, num_tables, P))
 
 
+def _build_store(tables: Sequence[np.ndarray]):
+    """Row-quantize ``tables`` to 8 bits and stack them into one device
+    store: ``(payload, scale, bias, offsets, sentinel, rows per table)``.
+    Zero rows after the tables (the first is the sentinel) pad the store to
+    whole kernel row groups, so the gather kernel needs no alignment
+    copy."""
+    rows = np.array([t.shape[0] for t in tables], np.int64)
+    pls, scs, bss = quantize_tables(tables)
+    R = int(rows.sum())
+    n_store = ops.aligned_rows(R + 1)
+    payload = np.zeros((n_store, pls[0].shape[1]), pls[0].dtype)
+    payload[:R] = np.concatenate(pls)
+    scale = np.zeros(n_store, np.float32)
+    scale[:R] = np.concatenate(scs)
+    bias = np.zeros(n_store, np.float32)
+    bias[:R] = np.concatenate(bss)
+    offsets = jnp.asarray(np.r_[0, np.cumsum(rows)[:-1]].astype(np.int32))
+    return (jnp.asarray(payload), jnp.asarray(scale), jnp.asarray(bias),
+            offsets, R, rows)
+
+
+# matmul precision of the dense half: float32 at full precision, which on a
+# TPU takes several bf16 passes; one pass misses the reference's logits
+DENSE_PRECISION = "highest"
+
+
 @dataclasses.dataclass
 class EngineConfig:
     hbm_cache_bytes: int = 8 << 20       # HBM budget for the row cache
@@ -122,10 +160,17 @@ class DeviceServingEngine:
     embedding dim (one backing store, one cache geometry). Rows are stored
     int8 row-quantized, the layout the paper's DWORD-granularity SM reads
     fetch (§4.1.1).
+
+    ``item_tables`` ({table_id: [rows, dim]}, the user tables' dim) and
+    ``dense`` (dense-half weights, ``models.dlrm.init_dense_half``), given
+    together, add the ranking side that ``rank_columnar`` serves; without
+    them the engine builds and compiles the user side alone.
     """
 
     def __init__(self, tables: Dict[int, np.ndarray], device: DeviceModel,
-                 cfg: Optional[EngineConfig] = None):
+                 cfg: Optional[EngineConfig] = None,
+                 item_tables: Optional[Dict[int, np.ndarray]] = None,
+                 dense: Optional[dict] = None):
         # None sentinel: a dataclass default instance here would be shared
         # (and mutable) across every engine constructed without a config
         cfg = EngineConfig() if cfg is None else cfg
@@ -140,24 +185,8 @@ class DeviceServingEngine:
         self.rows_per_table = np.array([tables[t].shape[0]
                                         for t in self.table_ids], np.int64)
 
-        # quantize and stack into one backing store; zero rows after the
-        # tables (the first is the sentinel) pad it to whole kernel row
-        # groups, so the gather kernel needs no alignment copy
-        pls, scs, bss = quantize_tables([tables[t] for t in self.table_ids])
-        R = int(self.rows_per_table.sum())
-        n_store = ops.aligned_rows(R + 1)
-        payload = np.zeros((n_store, self.dim), pls[0].dtype)
-        payload[:R] = np.concatenate(pls)
-        scale = np.zeros(n_store, np.float32)
-        scale[:R] = np.concatenate(scs)
-        bias = np.zeros(n_store, np.float32)
-        bias[:R] = np.concatenate(bss)
-        self.payload = jnp.asarray(payload)
-        self.scale = jnp.asarray(scale)
-        self.bias = jnp.asarray(bias)
-        self.sentinel = R                                    # the zero row
-        self.offsets = jnp.asarray(
-            np.r_[0, np.cumsum(self.rows_per_table)[:-1]].astype(np.int32))
+        (self.payload, self.scale, self.bias, self.offsets, self.sentinel,
+         _) = _build_store([tables[t] for t in self.table_ids])
 
         self.row_bytes = row_bytes(self.dim, bits=8)
         geo = dual_cache_geometry(cfg.hbm_cache_bytes, dim=self.dim,
@@ -169,7 +198,29 @@ class DeviceServingEngine:
         self.stats = QueryStats()        # store-level totals, host-plane shape
         self.telemetry = None            # obs handle; None = bit-invisible
         self.table_slot = {t: i for i, t in enumerate(self.table_ids)}
-        self._step = jax.jit(self._make_step())
+        step = self._make_step()
+        self._step = jax.jit(step)
+        self._rank_step = None
+        if (item_tables is None) != (dense is None):
+            raise ValueError("item tables and dense weights come together")
+        if item_tables is not None:
+            self._init_items(item_tables, dense, step)
+
+    def _init_items(self, item_tables, dense, step):
+        """The FM-direct item store, the dense weights on the device and
+        the jitted ranking step around the user ``step``."""
+        if {t.shape[1] for t in item_tables.values()} != {self.dim}:
+            raise ValueError(f"item tables must have the user tables' dim "
+                             f"{self.dim}")
+        self.item_ids: List[int] = list(item_tables)
+        self.item_slot = {t: i for i, t in enumerate(self.item_ids)}
+        (self.item_payload, self.item_scale, self.item_bias,
+         self.item_offsets, self.item_sentinel,
+         self.item_rows) = _build_store([item_tables[t]
+                                         for t in self.item_ids])
+        self.dense = jax.tree_util.tree_map(jnp.asarray, dense)
+        self.num_dense = int(dense["bottom"][0]["w"].shape[0])
+        self._rank_step = jax.jit(self._make_rank_step(step))
 
     # -- device step ----------------------------------------------------------
 
@@ -226,6 +277,43 @@ class DeviceServingEngine:
 
         return step
 
+    def _make_rank_step(self, step):
+        cfg = self.cfg
+        offsets, sentinel = self.item_offsets, self.item_sentinel
+
+        def rank_step(state, payload, scale, bias, idx, valid, item_payload,
+                      item_scale, item_bias, iidx, ivalid, dense, params):
+            # the user step, exactly as serve_columnar runs it: each
+            # query's user bags are served once (B_U = 1)
+            state, pooled, miss_counts = step(state, payload, scale, bias,
+                                              idx, valid)
+            B = pooled.shape[0]
+            N, Ti, P = iidx.shape                    # N = B x item batch
+            with jax.named_scope(names.SCOPE_ITEM):
+                # FM-direct: every item lookup gathers from the item
+                # store; padded positions point at its zero sentinel row
+                tids = jnp.arange(Ti, dtype=jnp.int32)[None, :, None]
+                gidx = jnp.where(ivalid, offsets[tids] + iidx, sentinel)
+                item_pooled = ops.embedding_gather_pool(
+                    item_payload, item_scale, item_bias,
+                    gidx.reshape(N * Ti, P).astype(jnp.int32),
+                    use_kernel=cfg.use_kernels).reshape(N, Ti, -1)
+            with jax.named_scope(names.SCOPE_DENSE):
+                # Eq. 2: a query's pooled user bags, broadcast over its
+                # candidates, beside each candidate's item bags
+                user = jnp.broadcast_to(
+                    pooled[:, None], (B, N // B) + pooled.shape[1:])
+                emb = jnp.concatenate(
+                    [user.reshape((N,) + pooled.shape[1:]), item_pooled],
+                    axis=1)
+                logits = dlrm.dense_logits(params, dense, emb,
+                                           precision=DENSE_PRECISION)
+                logits = logits.reshape(B, N // B)
+                scores = jax.nn.sigmoid(logits)
+            return state, scores, logits, miss_counts
+
+        return rank_step
+
     def _step_args(self, idx, valid):
         # the store goes in as arguments: arrays a jitted function closes
         # over are embedded in the program as constants
@@ -236,6 +324,12 @@ class DeviceServingEngine:
         """The jitted device step lowered for this ``[B, T, P]`` block, for
         inspecting what it compiles to (kernels, memory)."""
         return self._step.lower(*self._step_args(idx, valid))
+
+    def _rank_args(self, idx, valid, iidx, ivalid, dense):
+        return self._step_args(idx, valid) + (
+            self.item_payload, self.item_scale, self.item_bias,
+            jnp.asarray(iidx), jnp.asarray(ivalid), jnp.asarray(dense),
+            self.dense)
 
     # -- serving --------------------------------------------------------------
 
@@ -253,23 +347,24 @@ class DeviceServingEngine:
         with TraceAnnotation(names.SPAN_ACCOUNT):
             return pooled, self._account(miss, bg_iops)
 
-    def _checked(self, idx, valid) -> Tuple[np.ndarray, np.ndarray]:
+    def _checked(self, idx, valid, rows: Optional[np.ndarray] = None
+                 ) -> Tuple[np.ndarray, np.ndarray]:
         """``(idx, valid)`` as int32 and bool ``[B, T, P]`` blocks; raises
-        on a wrong shape or a valid row index out of its table's range."""
+        on a wrong shape or a valid row index out of its table's range.
+        ``rows``: rows per table, by default the user tables'."""
+        rows = self.rows_per_table if rows is None else rows
         with TraceAnnotation(names.SPAN_VALIDATE):
             idx = np.asarray(idx, np.int32)
             if idx.ndim != 3:
                 raise ValueError(
                     f"idx must be [B, T, P], got shape {idx.shape}")
-            if idx.shape[1] != len(self.table_ids):
+            if idx.shape[1] != len(rows):
                 raise ValueError(
-                    f"idx has {idx.shape[1]} tables, engine has "
-                    f"{len(self.table_ids)}")
+                    f"idx has {idx.shape[1]} tables, engine has {len(rows)}")
             if valid is None:
                 valid = np.ones(idx.shape, bool)
             live = np.where(valid, idx, 0)
-            if ((live < 0).any()
-                    or (live >= self.rows_per_table[None, :, None]).any()):
+            if (live < 0).any() or (live >= rows[None, :, None]).any():
                 raise ValueError("row index out of range")
         return idx, valid
 
@@ -278,15 +373,25 @@ class DeviceServingEngine:
         """Run the device step on a checked, non-empty block and wait for
         it: ``(pooled [B, T, dim], deduped miss counts [B, T])`` on the
         host."""
+        self._count("engine.positions", "engine.valid_positions", valid)
+        return self._run(self._step, self._step_args(idx, valid))
+
+    def _count(self, positions: str, valid_positions: str, valid) -> None:
+        """With a telemetry handle, add a block's positions and valid
+        positions to the two counters."""
         if self.telemetry is not None:
             reg = self.telemetry.registry
-            reg.inc("engine.positions", valid.size)
-            reg.inc("engine.valid_positions", int(np.count_nonzero(valid)))
+            reg.inc(positions, valid.size)
+            reg.inc(valid_positions, int(np.count_nonzero(valid)))
+
+    def _run(self, step, args) -> List[np.ndarray]:
+        """Dispatch a jitted step, keep its new cache state and wait for
+        the rest of its outputs on the host."""
         with TraceAnnotation(names.SPAN_DISPATCH):
-            state, pooled, miss = self._step(*self._step_args(idx, valid))
+            state, *outs = step(*args)
         self.state = state
         with TraceAnnotation(names.SPAN_FETCH):
-            return np.asarray(pooled), np.asarray(miss)
+            return [np.asarray(o) for o in outs]
 
     def serve_columnar(self, chunk: ColumnarChunk, bg_iops: float = 0.0
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -304,10 +409,54 @@ class DeviceServingEngine:
                 idx, valid = dense_from_chunk(chunk, self.table_slot, T)
             pooled, miss = self._run_step(*self._checked(idx, valid))
             with TraceAnnotation(names.SPAN_ACCOUNT):
-                stats = self._account(miss, bg_iops)
-                return (pooled,
-                        np.array([s.sm_time_us for s in stats], np.float64),
-                        np.array([s.sm_ios for s in stats], np.int64))
+                return (pooled,) + self._account_arrays(miss, bg_iops)
+
+    def rank_columnar(self, user_chunk: ColumnarChunk,
+                      item_chunk: ColumnarChunk, dense: np.ndarray):
+        """Rank a chunk's candidates in one device step (§2.2, Eq. 2).
+
+        ``user_chunk``: B queries over the user tables. ``item_chunk``: one
+        "query" per candidate over the item tables, ``B x item_batch`` of
+        them, query ``b``'s candidates in rows ``b * item_batch`` on.
+        ``dense``: ``[B x item_batch, num_dense]`` float32 features.
+        Returns ``(scores [B, item_batch] f32, logits [B, item_batch] f32,
+        sm_time_us [B] f64, sm_ios [B] i64)``; the SM reads and their
+        latency are the user side's, exactly as ``serve_columnar`` gives
+        them for ``user_chunk``."""
+        if self._rank_step is None:
+            raise ValueError("this engine has no item side")
+        B, N = user_chunk.n_queries, item_chunk.n_queries
+        dense = np.asarray(dense, np.float32)
+        if B == 0 or N % B or dense.shape != (N, self.num_dense):
+            raise ValueError(
+                f"{N} candidates and dense features {dense.shape} do not "
+                f"rank {B} queries with {self.num_dense} dense features")
+        with TraceAnnotation(names.SPAN_SERVE):
+            with TraceAnnotation(names.SPAN_PACK):
+                idx, valid = dense_from_chunk(user_chunk, self.table_slot,
+                                              len(self.table_ids))
+            with TraceAnnotation(names.SPAN_ITEM_PACK):
+                iidx, ivalid = dense_from_chunk(item_chunk, self.item_slot,
+                                                len(self.item_ids))
+            idx, valid = self._checked(idx, valid)
+            iidx, ivalid = self._checked(iidx, ivalid, self.item_rows)
+            self._count("engine.positions", "engine.valid_positions", valid)
+            self._count("engine.item_positions",
+                        "engine.item_valid_positions", ivalid)
+            if self.telemetry is not None:
+                self.telemetry.registry.inc("engine.items_scored", N)
+            scores, logits, miss = self._run(
+                self._rank_step,
+                self._rank_args(idx, valid, iidx, ivalid, dense))
+            with TraceAnnotation(names.SPAN_ACCOUNT):
+                return (scores, logits) + self._account_arrays(miss, 0.0)
+
+    def _account_arrays(self, miss: np.ndarray, bg_iops: float):
+        """:meth:`_account` as per-query arrays ``(sm_time_us f64, sm_ios
+        i64)``."""
+        stats = self._account(miss, bg_iops)
+        return (np.array([s.sm_time_us for s in stats], np.float64),
+                np.array([s.sm_ios for s in stats], np.int64))
 
     def _account(self, miss: np.ndarray, bg_iops: float) -> List[QueryStats]:
         """Per-query IO + Eq. 3 latency accounting for a ``[B, T]`` block of

@@ -19,6 +19,9 @@ from repro.kernels.gather_pool import gather_pool
 
 B, T, P, D = 32, 61, 64, 128      # chunk, M1 user tables, pooling, row width
 ROWS = 8_148_824                  # the smoke store: ~8.1M rows, 1 GiB
+# M1's item side (dlrm-m1-rank): 50 candidates a query over 30 item
+# tables, bags padded to 32, an FM-direct store of 3.7M rows
+ITEM_BAGS, ITEM_P, ITEM_ROWS = B * 50 * 30, 32, 3_719_192
 SETS, WAYS = 3640, 8              # a 4 MiB row cache
 
 
@@ -77,3 +80,16 @@ def test_cache_probe_compiles_for_v5e(one_chip):
          ((n,), jnp.int32), ((n,), jnp.int32)], one_chip)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().output_size_in_bytes >= n * (D + 1) * 4
+
+
+def test_item_gather_pool_compiles_for_v5e(one_chip):
+    """The ranking step's item block through the same kernel."""
+    compiled = _compile(
+        lambda pay, sc, bi, idx: gather_pool(pay, sc, bi, idx,
+                                             interpret=False),
+        [((ITEM_ROWS, D), jnp.uint8), ((ITEM_ROWS,), jnp.float32),
+         ((ITEM_ROWS,), jnp.float32), ((ITEM_BAGS, ITEM_P), jnp.int32)],
+        one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes == (
+        ITEM_BAGS * D * 4)
